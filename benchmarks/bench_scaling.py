@@ -1,9 +1,9 @@
 """Fig 13 analog: strong scaling of distributed SUBGRAPH2VEC.
 
-The container exposes one physical core, so wall-time across host-device
-counts measures dispatch overhead, not hardware scaling; the meaningful
-strong-scaling evidence on this host is the **per-shard resource scaling**
-extracted from the compiled artifact at mesh sizes 1/2/4/8:
+On virtual CPU devices, wall-time across device counts measures dispatch
+overhead, not hardware scaling; the strong-scaling evidence there is the
+**per-shard resource scaling** extracted from the compiled artifact at mesh
+sizes 1/2/4/8:
 
 * per-shard M-matrix bytes (the paper's Fig 12 memory-extension claim),
 * per-shard HLO flops (compute splits linearly),
@@ -19,7 +19,10 @@ win), ``per_shard_byte_frac`` (transient footprint of the ring arm as a
 fraction of blocking's), and ``overlap_eff`` (measured fraction of the
 modeled wire time hidden).
 
-Runs in a subprocess (needs its own XLA_FLAGS device count).
+On CPU the mesh sizes run in a child process with eight virtual devices
+(``XLA_FLAGS`` is read when the child's backend starts).  On a chip host they
+run in this process over the real devices: a parent that holds the chips
+cannot hand them to a child.
 """
 
 from __future__ import annotations
@@ -32,54 +35,79 @@ import tempfile
 
 from .common import record
 
-_CHILD = r"""
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-import json, time
-import jax, jax.numpy as jnp, numpy as np
-from repro import compat
-from repro.core import CountingEngine, get_template, rmat_graph
-from repro.launch.roofline import collective_wire_bytes
 
-g = rmat_graph(16384, 160_000, seed=7)
-t = get_template("u7")
-colors = jnp.asarray(np.random.default_rng(0).integers(0, t.k, size=(1, g.n)))
-out = []
-for n_dev in (1, 2, 4, 8):
-    mesh = jax.make_mesh((n_dev,), ("data",))
-    # the engine's mesh backend: one-coloring chunk for the per-shard probe
-    eng = CountingEngine(g, [t], backend="mesh", mesh=mesh, column_batch=8,
-                         ema_mode="loop", chunk_size=1)
-    with compat.set_mesh(mesh):
-        jitted = jax.jit(eng.backend_impl.counts_for_colors)
-        compiled = jitted.lower(colors).compile()
-        val = float(jitted(colors)[0, 0])
-        t0 = time.perf_counter(); jax.block_until_ready(jitted(colors)); dt = time.perf_counter() - t0
-    ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):  # JAX 0.4.x returns [dict]
-        ca = ca[0] if ca else {}
-    coll, _ = collective_wire_bytes(compiled.as_text())
-    out.append({
-        "devices": n_dev,
-        "wall_s": dt,
-        "flops_per_shard": ca.get("flops", 0.0),
-        "bytes_per_shard": ca.get("bytes accessed", 0.0),
-        "collective_bytes": coll,
-        "count": val,
-    })
-print("RESULT " + json.dumps(out))
+def _strong_scaling(device_counts):
+    """Per-shard compile figures and one timed launch per mesh size, over
+    the first ``d`` devices of this process for each ``d``."""
+    import time
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from repro.core import CountingEngine, get_template, rmat_graph
+    from repro.launch.roofline import collective_wire_bytes
+
+    g = rmat_graph(16384, 160_000, seed=7)
+    t = get_template("u7")
+    colors = jnp.asarray(np.random.default_rng(0).integers(0, t.k, size=(1, g.n)))
+    out = []
+    for n_dev in device_counts:
+        mesh = jax.make_mesh((n_dev,), ("data",), devices=jax.devices()[:n_dev])
+        # the engine's mesh backend: one-coloring chunk for the per-shard probe
+        eng = CountingEngine(g, [t], backend="mesh", mesh=mesh, column_batch=8,
+                             ema_mode="loop", chunk_size=1)
+        run = eng.backend_impl.jit(eng.backend_impl.counts_for_colors)
+        compiled = run.lower(colors).compile()
+        val = float(run(colors)[0, 0])
+        t0 = time.perf_counter()
+        jax.block_until_ready(run(colors))
+        dt = time.perf_counter() - t0
+        ca = compiled.cost_analysis() or {}
+        coll, _ = collective_wire_bytes(compiled.as_text())
+        out.append({
+            "devices": n_dev,
+            "wall_s": dt,
+            "flops_per_shard": ca.get("flops", 0.0),
+            "bytes_per_shard": ca.get("bytes accessed", 0.0),
+            "collective_bytes": coll,
+            "count": val,
+        })
+    return out
+
+
+_CHILD = r"""
+import json
+from benchmarks.bench_scaling import _strong_scaling
+print("RESULT " + json.dumps(_strong_scaling((1, 2, 4, 8))))
 """
 
 
-def run() -> None:
+def _cpu_env() -> dict:
+    """Environment for a child with eight virtual CPU devices (the device
+    count is read once, when the child's JAX backend starts)."""
     env = dict(os.environ)
-    env["PYTHONPATH"] = "src"
-    env.pop("XLA_FLAGS", None)
-    proc = subprocess.run(
-        [sys.executable, "-c", _CHILD], capture_output=True, text=True, env=env, timeout=900
-    )
-    line = next(l for l in proc.stdout.splitlines() if l.startswith("RESULT "))
-    data = json.loads(line[len("RESULT "):])
+    env["PYTHONPATH"] = os.pathsep.join(["src", "."])
+    env["JAX_PLATFORMS"] = "cpu"
+    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    env.pop("REPRO_MESH_COMM", None)
+    return env
+
+
+def run() -> None:
+    import jax
+
+    if jax.default_backend() == "cpu":
+        proc = subprocess.run(
+            [sys.executable, "-c", _CHILD], capture_output=True, text=True,
+            env=_cpu_env(), timeout=900,
+        )
+        line = next(l for l in proc.stdout.splitlines() if l.startswith("RESULT "))
+        data = json.loads(line[len("RESULT "):])
+    else:
+        # one process per chip: the mesh sizes run here, over real devices
+        n = len(jax.devices())
+        data = _strong_scaling(tuple(d for d in (1, 2, 4, 8) if d <= n))
     base = data[0]
     counts = [d["count"] for d in data]
     spread = (max(counts) - min(counts)) / max(abs(counts[0]), 1e-9)
@@ -95,31 +123,37 @@ def run() -> None:
     _run_ring()
 
 
+def _ring_args(n_dev: int, out: str) -> list:
+    return [
+        "--devices", str(n_dev), "--template", "u7",
+        "--n", "65536", "--edges", "262144",
+        "--column-batch", "256", "--chunk-size", "2",
+        "--iters", "2", "--repeats", "2", "--out", out,
+    ]
+
+
 def _run_ring() -> None:
     """fig13/ring rows: interleaved blocking-vs-pipelined A/B per mesh size.
 
-    Shells out to the perf driver (it owns XLA_FLAGS and the interleaving
-    discipline); the config is sized so the all-gathered buffer
-    (n_padded x B x cb ~ 256 MB) spills cache while a ring slice does not —
-    that locality gap is the honest ring win measurable on a single host,
-    where true comm/compute overlap cannot show.
+    Runs the perf driver (it owns the interleaving discipline); the config
+    is sized so the all-gathered buffer (n_padded x B x cb ~ 256 MB) spills
+    cache while a ring slice does not.  On CPU each mesh size runs in a
+    child with virtual devices; on a chip host it runs here, over real
+    devices.
     """
-    env = dict(os.environ)
-    env["PYTHONPATH"] = "src"
-    env.pop("XLA_FLAGS", None)
-    env.pop("REPRO_MESH_COMM", None)
-    for n_dev in (4, 8):
+    import jax
+
+    on_cpu = jax.default_backend() == "cpu"
+    sizes = (4, 8) if on_cpu else tuple(d for d in (4, 8) if d <= len(jax.devices()))
+    for n_dev in sizes:
         out = os.path.join(tempfile.mkdtemp(prefix="fig13_ring_"), "ab.json")
-        subprocess.run(
-            [
-                sys.executable, "scripts/perf_subgraph_u20.py",
-                "--devices", str(n_dev), "--template", "u7",
-                "--n", "65536", "--edges", "262144",
-                "--column-batch", "256", "--chunk-size", "2",
-                "--iters", "2", "--repeats", "2", "--out", out,
-            ],
-            check=True, capture_output=True, text=True, env=env, timeout=1800,
-        )
+        if on_cpu:
+            subprocess.run(
+                [sys.executable, "scripts/perf_subgraph_u20.py", *_ring_args(n_dev, out)],
+                check=True, capture_output=True, text=True, env=_cpu_env(), timeout=1800,
+            )
+        else:
+            _perf_driver().main(_ring_args(n_dev, out))
         with open(out) as fh:
             ab = json.load(fh)
         assert ab["bit_exact"], f"A/B arms diverged at {n_dev} devices"
@@ -130,3 +164,15 @@ def _run_ring() -> None:
             f"per_shard_byte_frac={ab['per_shard_byte_fraction']:.3f};"
             f"overlap_eff={ab['measured_overlap_efficiency']:.2f}",
         )
+
+
+def _perf_driver():
+    """``scripts/perf_subgraph_u20.py`` as a module (scripts/ is no package)."""
+    import importlib.util
+
+    spec = importlib.util.spec_from_file_location(
+        "perf_subgraph_u20", os.path.join("scripts", "perf_subgraph_u20.py")
+    )
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod

@@ -26,6 +26,8 @@ from . import (
     bench_template_scaling,
     bench_tuning,
 )
+from repro.compile_cache import enable_compile_cache
+
 from .common import ROWS, emit_header
 
 BENCHES = {
@@ -169,6 +171,7 @@ def main() -> int:
         "rows), merged into the JSON so the trend diff still flags them",
     )
     args = ap.parse_args()
+    enable_compile_cache()
     emit_header()
     failed = []
     if args.quick:

@@ -4,15 +4,18 @@ Runs the engine's ``mesh`` backend (vertex 1-D partition + column-batched
 all-gather SpMM + streamed eMA under ``shard_map``) on a multi-device host
 mesh and cross-checks against the single-device local engine.
 
-  PYTHONPATH=src python examples/distributed_counting.py
+  PYTHONPATH=src python examples/distributed_counting.py                     # every chip
+  JAX_PLATFORMS=cpu PYTHONPATH=src python examples/distributed_counting.py   # 8 CPU devices
 
-The device count comes from ``XLA_FLAGS`` (8 virtual host devices by
-default; set ``--xla_force_host_platform_device_count=N`` to change it).
+On an accelerator host the mesh spans every chip.  On the CPU backend it
+spans virtual host devices: 8 by default, or what
+``XLA_FLAGS=--xla_force_host_platform_device_count=N`` asks for.
 """
 
 import os
 
-os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
+if os.environ.get("JAX_PLATFORMS") == "cpu":
+    os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 
 import jax
 import numpy as np

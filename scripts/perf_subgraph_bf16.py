@@ -8,7 +8,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro.configs.registry import SUBGRAPH_SHAPES
 from repro.core import build_counting_plan
 from repro.core.distributed import distributed_input_specs, make_distributed_count_fn
@@ -32,7 +31,7 @@ for name, gd in (("fp32_gather", None), ("bf16_gather", jnp.bfloat16)):
     specs = distributed_input_specs(n_padded, n_shards, edges_per_shard)
     every = tuple(mesh.axis_names)
     in_sh = tuple(NamedSharding(mesh, P(every)) for _ in specs)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         compiled = jax.jit(fn, in_shardings=in_sh).lower(*specs).compile()
     ms = compiled.memory_analysis()
     resident = ms.argument_size_in_bytes + ms.temp_size_in_bytes + max(

@@ -82,7 +82,7 @@ def run_ab(args):
 
     g = rmat_graph(args.n, args.edges, seed=7)
     t = get_template(args.template)
-    mesh = jax.make_mesh((args.devices,), ("dev",))
+    mesh = jax.make_mesh((args.devices,), ("dev",), devices=jax.devices()[: args.devices])
     keys = jax.random.split(jax.random.PRNGKey(0), args.iters)
 
     modes = ("blocking", "pipelined") if args.comm == "both" else (args.comm,)
@@ -147,7 +147,6 @@ def run_static(args):
     import jax
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from repro import compat
     from repro.configs.registry import SUBGRAPH_SHAPES
     from repro.core import build_counting_plan
     from repro.core.colorsets import binom
@@ -186,7 +185,7 @@ def run_static(args):
                                         edges_per_shard)
         every = tuple(mesh.axis_names)
         in_sh = tuple(NamedSharding(mesh, P(every)) for _ in specs)
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             compiled = jax.jit(fn, in_shardings=in_sh).lower(*specs).compile()
         ms = compiled.memory_analysis()
         resident = ms.argument_size_in_bytes + ms.temp_size_in_bytes + max(
@@ -207,13 +206,16 @@ def run_static(args):
 
 def main(argv=None):
     args = parse_args(argv)
-    # XLA_FLAGS must be set before jax imports — which is why every import
-    # of jax/repro in this script is function-local
-    devices = 512 if args.static else args.devices
-    os.environ["XLA_FLAGS"] = (
-        os.environ.get("XLA_FLAGS", "")
-        + f" --xla_force_host_platform_device_count={devices}"
-    ).strip()
+    if "jax" not in sys.modules:
+        # run as a script: give the CPU backend its virtual devices.  XLA_FLAGS
+        # must be set before jax imports — which is why every import of
+        # jax/repro in this script is function-local.  A caller that already
+        # holds a backend (a chip host) brings its own devices.
+        devices = 512 if args.static else args.devices
+        os.environ["XLA_FLAGS"] = (
+            os.environ.get("XLA_FLAGS", "")
+            + f" --xla_force_host_platform_device_count={devices}"
+        ).strip()
     out = run_static(args) if args.static else run_ab(args)
     os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)
     with open(args.out, "w") as f:

@@ -204,7 +204,9 @@ def spmm_edges(src: jnp.ndarray, dst: jnp.ndarray, n: int, m: jnp.ndarray) -> jn
 def spmm_ell(nbr: jnp.ndarray, mask: jnp.ndarray, m: jnp.ndarray) -> jnp.ndarray:
     """``B[i] = sum_d mask[i,d] * M[nbr[i,d]]`` — padded row-gather reduction."""
     gathered = m[nbr]  # (n, max_deg, C)
-    return jnp.einsum("ndc,nd->nc", gathered, mask.astype(m.dtype))
+    return jnp.einsum(
+        "ndc,nd->nc", gathered, mask.astype(m.dtype), precision=jax.lax.Precision.HIGHEST
+    )
 
 
 def _ema_apply(

@@ -45,7 +45,6 @@ import numpy as np
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
-from .. import compat
 from .colorsets import binom
 from .counting import CountingPlan, _ema_apply_fused, schedule_liveness
 from .graph import Graph
@@ -206,20 +205,50 @@ def _compressed_gather(x, axes, gather_dtype):
 
 def _pvary_missing(x, axes):
     """Mark ``x`` varying over any mesh axes it is not already varying on
-    (loop-carry inits must match the varying type of the loop body).  On JAX
-    without the vma type system this is an identity (compat shims)."""
-    vma = compat.varying_axes(x)
+    (loop-carry inits must match the varying type of the loop body)."""
+    vma = jax.typeof(x).vma
     missing = tuple(a for a in axes if a not in vma)
-    return compat.pvary(x, missing) if missing else x
+    return jax.lax.pcast(x, missing, to="varying") if missing else x
+
+
+#: Most split entries the streamed eMA multiplies in one step: its product
+#: is ``(rows, B, entries)``, and a wide stage holds thousands of entries
+#: per passive column batch.
+EMA_ENTRY_CHUNK = 128
+
+
+def _gather_segment_sum(table, idx, dst, mask, rows: int, accum_dtype, axes):
+    """``sum_e table[idx[e]] * mask[e]`` into row ``dst[e]``, ``(rows, ...)``.
+
+    Edge lists longer than :data:`~repro.plan.cost.EDGE_CHUNK` are reduced a
+    chunk at a time, so the gathered messages never exist for every edge at
+    once (on a TPU their small minor dims pad to 128-lane tiles).
+    """
+    from repro.plan.cost import EDGE_CHUNK
+
+    n_e = idx.shape[0]
+    n_chunks = max(-(-n_e // EDGE_CHUNK), 1)
+    chunk = -(-n_e // n_chunks)
+
+    def body(i, acc):
+        pos = i * chunk + jnp.arange(chunk)
+        weight = jnp.where(pos < n_e, mask[jnp.minimum(pos, n_e - 1)], 0)
+        pos = jnp.minimum(pos, n_e - 1)
+        vals = table[idx[pos]].astype(accum_dtype) * weight[:, None, None]
+        return acc + jax.ops.segment_sum(vals, dst[pos], num_segments=rows)
+
+    init = _pvary_missing(jnp.zeros((rows,) + table.shape[1:], accum_dtype), axes)
+    return jax.lax.fori_loop(0, n_chunks, body, init)
 
 
 def _streamed_stage_tables(table, column_batch: int):
     """Re-bucket one stage's split table by passive-column batch.
 
     Returns ``(ent_out, ent_ia, ent_ip_local, ent_valid)`` shaped
-    ``(n_batches, cap)`` (padded per batch): for batch ``bi`` the streamed
-    schedule applies exactly the (out, split) entries whose passive column
-    falls in that batch.
+    ``(n_batches, cap)`` (padded per batch, to whole
+    :data:`EMA_ENTRY_CHUNK` chunks once wider than one): for batch ``bi``
+    the streamed schedule applies exactly the (out, split) entries whose
+    passive column falls in that batch.
     """
     n_out, n_splits = table.idx_a.shape
     flat_out = np.repeat(np.arange(n_out, dtype=np.int32), n_splits)
@@ -234,6 +263,9 @@ def _streamed_stage_tables(table, column_batch: int):
     )
     counts = np.bincount(bucket, minlength=n_batches)
     cap = int(counts.max(initial=1))
+    if cap > EMA_ENTRY_CHUNK:
+        # whole entry chunks: the streamed eMA folds one chunk at a time
+        cap = -(-cap // EMA_ENTRY_CHUNK) * EMA_ENTRY_CHUNK
     ent_out = np.zeros((n_batches, cap), np.int32)
     ent_ia = np.zeros((n_batches, cap), np.int32)
     ent_ip = np.zeros((n_batches, cap), np.int32)
@@ -438,8 +470,9 @@ def make_batched_count_fn(
         bsz, c_pad = m_p.shape[1], m_p.shape[2]
         if column_batch is None:
             full = _compressed_gather(m_p, axes, gather_dtype)
-            msgs = full[src].astype(accum_dtype) * edge_mask[:, None, None]
-            return jax.ops.segment_sum(msgs, dst_local, num_segments=rows)
+            return _gather_segment_sum(
+                full, src, dst_local, edge_mask, rows, accum_dtype, axes
+            )
         n_batches = c_pad // column_batch
 
         def body(b_idx, acc):
@@ -447,8 +480,9 @@ def make_batched_count_fn(
                 m_p, (0, 0, b_idx * column_batch), (rows, bsz, column_batch)
             )
             full = _compressed_gather(cols, axes, gather_dtype)
-            msgs = full[src].astype(accum_dtype) * edge_mask[:, None, None]
-            bcol = jax.ops.segment_sum(msgs, dst_local, num_segments=rows)
+            bcol = _gather_segment_sum(
+                full, src, dst_local, edge_mask, rows, accum_dtype, axes
+            )
             return jax.lax.dynamic_update_slice(acc, bcol, (0, 0, b_idx * column_batch))
 
         init = _pvary_missing(jnp.zeros(m_p.shape, accum_dtype), axes)
@@ -492,9 +526,8 @@ def make_batched_count_fn(
             # valid slots sit in the owner's row range by the bucket
             # invariant; pad slots (mask 0) are clipped in-bounds and zeroed
             local = jnp.clip(b_src - owner * rows, 0, rows - 1)
-            vals = block[local].astype(accum_dtype) * b_mask[:, None, None]
-            bcol = bcol + jax.ops.segment_sum(
-                vals, b_dst, num_segments=rows
+            bcol = bcol + _gather_segment_sum(
+                block, local, b_dst, b_mask, rows, accum_dtype, axes
             )
         return bcol
 
@@ -558,19 +591,29 @@ def make_batched_count_fn(
                 )
             else:
                 full = _compressed_gather(cols, axes, gather_dtype)
-                msgs = full[src].astype(accum_dtype) * edge_mask[:, None, None]
-                bcol = jax.ops.segment_sum(msgs, dst_local, num_segments=rows)
-            eo = jax.lax.dynamic_index_in_dim(ent_out, b_idx, keepdims=False)
-            ia = jax.lax.dynamic_index_in_dim(ent_ia, b_idx, keepdims=False)
-            ip = jax.lax.dynamic_index_in_dim(ent_ip, b_idx, keepdims=False)
-            va = jax.lax.dynamic_index_in_dim(ent_valid, b_idx, keepdims=False)
-            prod = (
-                jnp.take(m_a, ia, axis=2).astype(accum_dtype)
-                * jnp.take(bcol, ip, axis=2)
-                * va[None, None, :].astype(accum_dtype)
-            )
-            return m_s.at[:, :, eo].add(prod)
+                bcol = _gather_segment_sum(
+                    full, src, dst_local, edge_mask, rows, accum_dtype, axes
+                )
+            row = [
+                jax.lax.dynamic_index_in_dim(a, b_idx, keepdims=False)
+                for a in (ent_out, ent_ia, ent_ip, ent_valid)
+            ]
 
+            def fold(j, m_s):
+                eo, ia, ip, va = (
+                    jax.lax.dynamic_slice_in_dim(a, j * width, width) for a in row
+                )
+                prod = (
+                    jnp.take(m_a, ia, axis=2).astype(accum_dtype)
+                    * jnp.take(bcol, ip, axis=2)
+                    * va[None, None, :].astype(accum_dtype)
+                )
+                return m_s.at[:, :, eo].add(prod)
+
+            return jax.lax.fori_loop(0, cap // width, fold, m_s)
+
+        cap = ent_out.shape[1]
+        width = min(cap, EMA_ENTRY_CHUNK)
         init = _pvary_missing(jnp.zeros((rows, bsz, n_out), accum_dtype), axes)
         return jax.lax.fori_loop(0, n_batches, body, init)
 
@@ -634,6 +677,7 @@ def make_batched_count_fn(
                                 "rbos,rbos->rbo",
                                 jnp.take(m_a, idx_a, axis=2).astype(accum_dtype),
                                 jnp.take(b, idx_p, axis=2),
+                                precision=jax.lax.Precision.HIGHEST,
                             )
                         else:
                             m_s = ema_loop(m_a, b, idx_a, idx_p)
@@ -650,7 +694,7 @@ def make_batched_count_fn(
         return jnp.stack(totals, axis=1).astype(jnp.float32)  # (B, T)
 
     sharded = P(axes)
-    mapped = compat.shard_map(
+    mapped = jax.shard_map(
         local_count,
         mesh=mesh,
         in_specs=(P(None, axes), sharded, sharded, sharded, table_specs),

@@ -42,7 +42,7 @@ from repro.exec.local import SELL_GROUP_SIZE
 from repro.exec.mesh import MeshBackend
 from repro.exec.select import (
     BACKEND_ENV_VAR,
-    BLOCKED_MIN_VERTICES,
+    BLOCKED_MAX_PADDING,
     DENSE_MAX_VERTICES,
     DENSE_WORK_ADVANTAGE,
     ELL_PAD_FACTOR,
@@ -56,6 +56,7 @@ from repro.plan.cost import (
     LOCAL_COLUMN_BATCH,
     MAX_CHUNK_SIZE,
     CostModel,
+    default_memory_budget_bytes,
     pick_chunk_size,
 )
 from repro.plan.ir import TemplatePlan, build_template_plan, template_set_canons
@@ -63,7 +64,7 @@ from repro.testing import faults as _faults
 
 from .colorsets import colorful_probability
 from .counting import CountingPlan
-from .graph import Graph
+from .graph import BLOCKED_BLOCK_SIZE, Graph
 from .templates import Template, sub_template_canonical
 
 __all__ = [
@@ -84,7 +85,7 @@ __all__ = [
     # re-exported tuning constants (homes: repro.plan.cost, repro.exec)
     "DEFAULT_MEMORY_BUDGET_BYTES", "MAX_CHUNK_SIZE", "LOCAL_COLUMN_BATCH",
     "BACKEND_ENV_VAR", "DENSE_MAX_VERTICES", "ELL_PAD_FACTOR",
-    "BLOCKED_MIN_VERTICES", "SELL_MIN_SCATTER_WORK", "SELL_GROUP_SIZE",
+    "BLOCKED_MAX_PADDING", "SELL_MIN_SCATTER_WORK", "SELL_GROUP_SIZE",
     "DENSE_WORK_ADVANTAGE",
 ]
 
@@ -213,7 +214,7 @@ def engine_cache_key(
         if memory_budget_bytes is None and cfg.memory_budget_bytes is not None:
             memory_budget_bytes = cfg.memory_budget_bytes
     if memory_budget_bytes is None:
-        memory_budget_bytes = DEFAULT_MEMORY_BUDGET_BYTES
+        memory_budget_bytes = default_memory_budget_bytes()
     return _assemble_cache_key(
         signature,
         canons,
@@ -245,7 +246,8 @@ class CountingEngine:
       memory_budget_bytes: live-footprint budget steering the chunk picker
         (per device — for the mesh backend the model is per shard).
         ``None`` resolves to the tuned config's budget (the tuner sweeps
-        it) when one binds, else ``DEFAULT_MEMORY_BUDGET_BYTES``.
+        it) when one binds, else a share of the device's reported memory
+        (``DEFAULT_MEMORY_BUDGET_BYTES`` where it reports none, as on CPU).
       chunk_size: explicit colorings-per-chunk override (skips the picker).
       plans: optional pre-built :class:`CountingPlan` per template.
       block_size / interpret: fused Pallas kernel knobs (``blocked``).
@@ -280,7 +282,7 @@ class CountingEngine:
         memory_budget_bytes: Optional[int] = None,
         chunk_size: Optional[int] = None,
         plans: Optional[Sequence[CountingPlan]] = None,
-        block_size: int = 256,
+        block_size: int = BLOCKED_BLOCK_SIZE,
         interpret: bool = False,
         mesh=None,
         column_batch: Optional[int] = None,
@@ -364,7 +366,7 @@ class CountingEngine:
         if memory_budget_bytes is None and self._tuning is not None:
             memory_budget_bytes = self._tuning.memory_budget_bytes
         self.memory_budget_bytes = int(
-            DEFAULT_MEMORY_BUDGET_BYTES
+            default_memory_budget_bytes()
             if memory_budget_bytes is None
             else memory_budget_bytes
         )
@@ -378,8 +380,9 @@ class CountingEngine:
             self.column_batch = self.cost.pick_local_column_batch()
 
         norm = colorful_probability(self.k)
-        self._norm_factors = jnp.asarray(
-            [1.0 / (norm * plan.automorphisms) for plan in self.plans], jnp.float32
+        # applied on the host in float64 (estimates outgrow fp32 first)
+        self._norm_factors = np.asarray(
+            [1.0 / (norm * plan.automorphisms) for plan in self.plans], np.float64
         )
 
         # Observability counters, Python-level: ``trace_count`` bumps once
@@ -408,7 +411,9 @@ class CountingEngine:
         self._chunk_explicit = bool(chunk_size)
         self._column_batch_arg = column_batch
         self.chunk_size = int(chunk_size) if chunk_size else self.cost.pick_chunk_size(
-            self.bytes_per_coloring(), self.memory_budget_bytes
+            self.bytes_per_coloring(),
+            self.memory_budget_bytes,
+            self.backend_impl.max_chunk_size(),
         )
 
         self._graph_signature: Optional[str] = None  # computed lazily
@@ -436,6 +441,7 @@ class CountingEngine:
 
         self._run_fn = None  # built lazily (jit cache)
         self._chunk_fn = None  # streaming per-chunk jit (serving path)
+        self._raw_fn = None  # fixed-coloring jit (raw_counts)
 
     # ------------------------------------------------------------------
     # Plan-derived views (compat names preserved for tests/benchmarks)
@@ -613,8 +619,10 @@ class CountingEngine:
 
     def raw_counts(self, colors) -> jnp.ndarray:
         """(n,) coloring -> (T,) raw colorful totals (test/inspection hook)."""
+        if self._raw_fn is None:
+            self._raw_fn = self.backend_impl.jit(self.backend_impl.counts_for_colors)
         colors = jnp.asarray(colors)
-        return self.backend_impl.counts_for_colors(colors[None, :])[0]
+        return self._raw_fn(colors[None, :])[0]
 
     def _get_run_fn(self):
         if self._run_fn is None:
@@ -629,7 +637,7 @@ class CountingEngine:
                 self.trace_count += 1
                 return impl.counts_for_keys_chunk(keys)
 
-            self._chunk_fn = jax.jit(chunk_run)
+            self._chunk_fn = impl.jit(chunk_run)
         return self._chunk_fn
 
     def count_keys_chunk(self, keys) -> np.ndarray:
@@ -673,7 +681,7 @@ class CountingEngine:
         if pad:
             keys = jnp.concatenate([keys, keys[-1:].repeat(pad, axis=0)], axis=0)
         vals = self._get_chunk_fn()(keys)
-        out = np.asarray(vals, dtype=np.float64)[:m]
+        out = np.asarray(vals, dtype=np.float64)[:m] * self._norm_factors
         return _faults.corrupt_result("launch", out, ctx=f"backend={self.backend}")
 
     def count_keys(self, keys) -> np.ndarray:
@@ -692,7 +700,7 @@ class CountingEngine:
             keys = jnp.concatenate([keys, keys[-1:].repeat(pad, axis=0)], axis=0)
         vals = self._get_run_fn()(keys.reshape(n_chunks, chunk, *keys.shape[1:]))
         flat = np.asarray(vals, dtype=np.float64).reshape(n_chunks * chunk, -1)
-        return flat[:iters]
+        return flat[:iters] * self._norm_factors
 
     def estimate(self, iterations: int = 32, seed: int = 0) -> List[EstimateResult]:
         """Run ``iterations`` random colorings; one :class:`EstimateResult`

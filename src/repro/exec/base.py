@@ -16,10 +16,16 @@ The third layer of the plan -> cost -> exec pipeline.  A backend owns:
 * **the memory-model geometry** — :meth:`transient_elements` /
   :meth:`resident_elements` feed the operand measurements into the plan
   layer's :class:`~repro.plan.cost.CostModel` formulas.
+
+The engine compiles the backend's programs through :meth:`EngineBackend.jit`,
+which hands the device operands to the program as arguments: a captured
+array would be embedded in the program as a constant, and a deployment-size
+edge list makes that program too large to compile.
 """
 
 from __future__ import annotations
 
+import contextlib
 from dataclasses import dataclass
 from typing import Callable, Dict, List, Sequence, Tuple
 
@@ -29,6 +35,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.colorsets import bucketed_split_entries
+from repro.plan.cost import MAX_CHUNK_SIZE
 
 __all__ = [
     "StageTables",
@@ -56,6 +63,8 @@ def make_backend(engine, **kwargs) -> "EngineBackend":
         MixedBackend,
         SellBackend,
     )
+    from repro.core.graph import BLOCKED_BLOCK_SIZE
+
     from .mesh import MeshBackend
 
     name = engine.backend
@@ -72,7 +81,7 @@ def make_backend(engine, **kwargs) -> "EngineBackend":
     if name == "dense":
         return DenseBackend(engine)
     if name == "blocked":
-        return BlockedEllBackend(engine, block_size=kwargs.get("block_size", 256))
+        return BlockedEllBackend(engine, block_size=kwargs.get("block_size", BLOCKED_BLOCK_SIZE))
     if name == "mesh":
         return MeshBackend(
             engine,
@@ -91,8 +100,8 @@ class StageTables:
     """Split tables for one DP stage, in both shapes the fused pipeline needs.
 
     ``idx_a_host`` / ``idx_p_host`` are the plain ``(n_out, n_splits)`` rank
-    tables, kept host-side: the fused Pallas kernel expands them per
-    coloring chunk at trace time (``spmm_ema_batched``).  ``batches`` are
+    tables, kept host-side: the fused Pallas kernel takes them flattened as
+    scalar prefetch (``spmm_ema_batched``).  ``batches`` are
     the same entries re-bucketed by passive-column batch and shipped to the
     device (:func:`repro.core.colorsets.bucketed_split_entries`) for the
     streamed pure-JAX executor.  De-duplicated across stages by
@@ -201,6 +210,12 @@ def build_bag_tables(plan) -> Dict[Tuple[int, int], BagStageTables]:
     return out
 
 
+def _is_operand(value) -> bool:
+    """A device array, or a pytree whose every leaf is one."""
+    leaves = jax.tree.leaves(value)
+    return bool(leaves) and all(isinstance(x, jax.Array) for x in leaves)
+
+
 class EngineBackend:
     """One fused SpMM+eMA execution strategy behind ``CountingEngine``.
 
@@ -247,9 +262,10 @@ class EngineBackend:
         raise NotImplementedError
 
     def counts_for_keys_chunk(self, keys_chunk: jnp.ndarray) -> jnp.ndarray:
-        """``(B, 2)`` PRNG keys -> ``(B, T)`` normalized estimates.
+        """``(B, 2)`` PRNG keys -> ``(B, T)`` un-normalized colorful totals.
 
-        The coloring draw is identical across backends (one ``randint`` per
+        The engine scales them to estimates on the host, in float64: a u12
+        estimate on a scale-20 RMAT exceeds the fp32 range.  The coloring draw is identical across backends (one ``randint`` per
         key over the *original* vertex ids), so the same keys produce the
         same colorings — and therefore fp-tolerance-comparable estimates —
         on every backend, mesh included.
@@ -258,7 +274,7 @@ class EngineBackend:
         colors = jax.vmap(
             lambda key: jax.random.randint(key, (eng.graph.n,), 0, eng.k)
         )(keys_chunk)
-        return self.counts_for_colors(colors) * eng._norm_factors[None, :]
+        return self.counts_for_colors(colors)
 
     def make_run_fn(self) -> Callable:
         """One jit for the whole run: ``lax.map`` over key chunks.
@@ -273,7 +289,54 @@ class EngineBackend:
             engine.trace_count += 1
             return jax.lax.map(self.counts_for_keys_chunk, keys)
 
-        return jax.jit(run)
+        return self.jit(run)
+
+    # -- device operands ----------------------------------------------------
+
+    def _operand_owners(self) -> Tuple["EngineBackend", ...]:
+        """Objects whose array attributes the programs read."""
+        return (self,)
+
+    def device_operands(self) -> Tuple[Dict[str, object], ...]:
+        """Per owner, the attributes holding device arrays (or pytrees of
+        them): the graph operands the jitted programs take as arguments."""
+        return tuple(
+            {k: v for k, v in vars(owner).items() if _is_operand(v)}
+            for owner in self._operand_owners()
+        )
+
+    @contextlib.contextmanager
+    def bind_operands(self, operands):
+        """Point the operand attributes at ``operands`` (tracers, inside a
+        trace) for the duration of the block."""
+        owners = self._operand_owners()
+        saved = [{k: getattr(o, k) for k in ops} for o, ops in zip(owners, operands)]
+        try:
+            for owner, ops in zip(owners, operands):
+                for k, v in ops.items():
+                    setattr(owner, k, v)
+            yield
+        finally:
+            for owner, old in zip(owners, saved):
+                for k, v in old.items():
+                    setattr(owner, k, v)
+
+    def jit(self, fn: Callable) -> Callable:
+        """``jax.jit(fn)`` that reads this backend's device operands as
+        program arguments.  The result is called like ``fn`` and has
+        ``.lower(*args)`` for compile-only inspection."""
+
+        def with_operands(operands, *args):
+            with self.bind_operands(operands):
+                return fn(*args)
+
+        jitted = jax.jit(with_operands)
+
+        def call(*args):
+            return jitted(self.device_operands(), *args)
+
+        call.lower = lambda *args: jitted.lower(self.device_operands(), *args)
+        return call
 
     # -- memory-model geometry ----------------------------------------------
 
@@ -293,3 +356,7 @@ class EngineBackend:
         return self.engine.cost.bytes_per_coloring(
             self.transient_elements(), self.resident_elements()
         )
+
+    def max_chunk_size(self) -> int:
+        """Most colorings one launch may fuse, whatever the memory budget."""
+        return MAX_CHUNK_SIZE

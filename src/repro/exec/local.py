@@ -13,11 +13,14 @@ from __future__ import annotations
 
 from typing import Callable, Dict
 
+import numpy as np
+
 import jax
 import jax.numpy as jnp
 
 from repro.core.counting import fused_aggregate_ema_grouped
-from repro.core.graph import build_sell
+from repro.core.graph import BLOCKED_BLOCK_SIZE, build_sell
+from repro.plan.cost import EDGE_CHUNK
 
 from .base import (
     BagStageTables,
@@ -300,22 +303,40 @@ class LocalBackend(EngineBackend):
 
 
 class EdgesBackend(LocalBackend):
-    """Edge-list gather + segment-sum (the skew-robust default)."""
+    """Edge-list gather + segment-sum (the skew-robust default).
+
+    Edge lists longer than :data:`EDGE_CHUNK` are reduced one chunk of edges
+    at a time, so the gathered ``(edges, B, c)`` messages never exist for
+    the whole graph: on a TPU their small minor dims pad to full 128-lane
+    tiles, and a deployment-size edge list would not fit the device.
+    """
 
     name = "edges"
 
     def __init__(self, engine, shared=None):
         super().__init__(engine, shared=shared)
         g = engine.graph
-        self._src = jnp.asarray(g.src)
-        self._dst = jnp.asarray(g.dst)
+        n_edges = g.num_directed
+        self._edge_chunk = min(n_edges, EDGE_CHUNK) or 1
+        pad = -n_edges % self._edge_chunk
+        # padded edges point past the last segment: segment_sum drops them
+        self._src = jnp.asarray(np.pad(g.src, (0, pad)))
+        self._dst = jnp.asarray(np.pad(g.dst, (0, pad), constant_values=g.n))
 
     def spmm(self, m):
-        return jax.ops.segment_sum(
-            m[self._src].astype(self.engine.policy.accum_dtype),
-            self._dst,
-            num_segments=self.engine.graph.n,
-            indices_are_sorted=True,
+        accum = self.engine.policy.accum_dtype
+        n = self.engine.graph.n
+        chunk = self._edge_chunk
+
+        def body(i, acc):
+            src = jax.lax.dynamic_slice_in_dim(self._src, i * chunk, chunk)
+            dst = jax.lax.dynamic_slice_in_dim(self._dst, i * chunk, chunk)
+            return acc + jax.ops.segment_sum(
+                m[src].astype(accum), dst, num_segments=n, indices_are_sorted=True
+            )
+
+        return jax.lax.fori_loop(
+            0, self._src.shape[0] // chunk, body, jnp.zeros(m.shape, accum)
         )
 
 
@@ -333,7 +354,10 @@ class EllBackend(LocalBackend):
     def spmm(self, m):
         pol = self.engine.policy
         gathered = m[self._nbr].astype(pol.accum_dtype)  # (n, max_deg, B, c)
-        return jnp.einsum("ndbc,nd->nbc", gathered, self._ell_mask.astype(pol.accum_dtype))
+        return jnp.einsum(
+            "ndbc,nd->nbc", gathered, self._ell_mask.astype(pol.accum_dtype),
+            precision=jax.lax.Precision.HIGHEST,
+        )
 
 
 class SellBackend(LocalBackend):
@@ -368,6 +392,7 @@ class SellBackend(LocalBackend):
                 "rdbc,rd->rbc",
                 m[nbr].astype(pol.accum_dtype),
                 mask.astype(pol.accum_dtype),
+                precision=jax.lax.Precision.HIGHEST,
             )
             for nbr, mask in self._groups
         ]
@@ -396,6 +421,7 @@ class DenseBackend(LocalBackend):
             self._adj.astype(pol.store_dtype),
             m.reshape(n, b * c),
             preferred_element_type=pol.accum_dtype,
+            precision=jax.lax.Precision.HIGHEST,
         )
         return out.reshape(n, b, c).astype(pol.accum_dtype)
 
@@ -412,7 +438,7 @@ class BlockedEllBackend(LocalBackend):
 
     name = "blocked"
 
-    def __init__(self, engine, block_size: int = 256, shared=None):
+    def __init__(self, engine, block_size: int = BLOCKED_BLOCK_SIZE, shared=None):
         super().__init__(engine, shared=shared)
         from repro.kernels.spmm_ema.ops import prepare_fused_operand
 
@@ -429,6 +455,21 @@ class BlockedEllBackend(LocalBackend):
             interpret=self.engine.interpret,
         )
         return out.reshape(n, b, c).astype(self.engine.policy.accum_dtype)
+
+    def max_chunk_size(self) -> int:
+        """Colorings whose bands fit the kernel's VMEM at the widest stage."""
+        from repro.kernels.spmm_ema.kernel import VMEM_BUDGET_BYTES, vmem_bytes
+
+        block = self._fused_op.blocked.block_size
+        per_coloring = max(
+            (
+                vmem_bytes(1, st.passive_columns, st.active_columns, st.columns, block)
+                for st in self.engine.plan_ir.stages
+                if st.table_key is not None
+            ),
+            default=1,
+        )
+        return max(1, min(super().max_chunk_size(), VMEM_BUDGET_BYTES // per_coloring))
 
     def aggregate_ema(self, m_p, m_a, tables: StageTables):
         from repro.kernels.spmm_ema.ops import spmm_ema_batched
@@ -512,6 +553,9 @@ class MixedBackend(LocalBackend):
             for name in sorted(names)
         }
         self._default = self._impls[tuning.default_backend]
+
+    def _operand_owners(self):
+        return (self, *self._impls.values())
 
     def spmm(self, m):
         return self._default.spmm(m)

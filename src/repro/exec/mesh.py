@@ -31,12 +31,29 @@ from typing import Optional
 
 import numpy as np
 
+import jax
 import jax.numpy as jnp
+from jax.sharding import AxisType, Mesh, NamedSharding
+from jax.sharding import PartitionSpec as P
 
 from .base import EngineBackend
 from .select import mesh_comm_mode
 
-__all__ = ["MeshBackend", "BagPlanUnsupported"]
+__all__ = ["MeshBackend", "BagPlanUnsupported", "auto_axes"]
+
+
+def auto_axes(mesh: Mesh) -> Mesh:
+    """``mesh`` over the same devices with every axis ``Auto``.
+
+    ``jax.make_mesh`` gives Explicit axes, whose sharding-in-types rules
+    require every jit that touches them to run under ``jax.set_mesh``.  The
+    DP program places its own data (``shard_map`` with full in/out specs),
+    so it runs on Auto axes and callers never need a mesh context.
+    """
+    if all(t == AxisType.Auto for t in mesh.axis_types):
+        return mesh
+    return Mesh(mesh.devices, mesh.axis_names,
+                axis_types=(AxisType.Auto,) * len(mesh.axis_names))
 
 
 class BagPlanUnsupported(NotImplementedError):
@@ -114,6 +131,7 @@ class MeshBackend(EngineBackend):
             raise ValueError(f"unknown mesh comm mode {comm!r}")
         from repro.core.distributed import make_batched_count_fn, shard_graph
 
+        mesh = auto_axes(mesh)
         self.mesh = mesh
         self.ema_mode = ema_mode
         self.gather_dtype = gather_dtype
@@ -192,13 +210,17 @@ class MeshBackend(EngineBackend):
             comm_schedule=stage_modes,
             bucket_stride=self.sharded.bucket_stride,
         )
-        self._src = jnp.asarray(self.sharded.src)
-        self._dst_local = jnp.asarray(self.sharded.dst_local)
-        self._edge_mask = jnp.asarray(self.sharded.edge_mask)
+        # each device holds its own edge partition; the relabel is replicated
+        by_shard = NamedSharding(mesh, P(tuple(mesh.axis_names)))
+        self._src = jax.device_put(self.sharded.src, by_shard)
+        self._dst_local = jax.device_put(self.sharded.dst_local, by_shard)
+        self._edge_mask = jax.device_put(self.sharded.edge_mask, by_shard)
         # colorings follow the degree-balancing relabel (scatter old -> new;
         # new ids range over [0, n_padded) with pad slots interleaved)
         self._perm = (
-            jnp.asarray(self.sharded.perm) if self.sharded.perm is not None else None
+            jax.device_put(self.sharded.perm, NamedSharding(mesh, P()))
+            if self.sharded.perm is not None
+            else None
         )
 
     def _pipeline_eligibility(self, n_shards: int):

@@ -29,7 +29,9 @@ __all__ = [
     "MESH_COMM_MODES",
     "DENSE_MAX_VERTICES",
     "ELL_PAD_FACTOR",
-    "BLOCKED_MIN_VERTICES",
+    "BLOCKED_MAX_PADDING",
+    "BLOCKED_MAX_ROWS",
+    "blocked_operand_fit",
     "SELL_MIN_SCATTER_WORK",
     "DENSE_WORK_ADVANTAGE",
 ]
@@ -43,8 +45,18 @@ DENSE_MAX_VERTICES = 256
 #: exceed this factor times the true directed edge count.
 ELL_PAD_FACTOR = 1.5
 
-#: On TPU, graphs at least this large route to the Pallas blocked-ELL kernel.
-BLOCKED_MIN_VERTICES = 4096
+#: On TPU the fused Pallas kernel is picked only when its blocked-ELL
+#: operand is mostly edges: padded operand bytes at most this factor of the
+#: unpadded edge bytes.  A sparse RMAT's off-diagonal (dst, src) block pairs
+#: hold a few edges each, and every one of them pads to a full row.
+BLOCKED_MAX_PADDING = 2.0
+
+#: Most operand rows the kernel may take: its per-row block ids and run
+#: flags (four int32 per row, 512 KiB here) ride in the 1 MiB SMEM as
+#: scalar prefetch, beside the split tables.  ``tests/test_tpu_compile.py``
+#: compiles both kernels at this bound with u12's widest stage; twice the
+#: rows overflows SMEM.
+BLOCKED_MAX_ROWS = 32768
 
 #: Environment variable overriding the auto-selected local backend.
 BACKEND_ENV_VAR = "REPRO_ENGINE_BACKEND"
@@ -94,12 +106,14 @@ def select_backend(graph, platform: Optional[str] = None, explain: bool = False)
       ``n^2 / DENSE_WORK_ADVANTAGE`` (avg degree ``>= n / 16``): one
       (n, n) matmul beats gather/scatter.  The DP column count cancels
       from the comparison — both paths scale linearly in it.
-    * ``blocked`` — large graphs on TPU: the fused Pallas blocked-ELL
-      SpMM+eMA kernel.
+    * ``blocked`` — on TPU, graphs whose blocked-ELL operand fits: the
+      fused Pallas SpMM+eMA kernel (see :func:`blocked_operand_fit`).
     * ``ell``     — flat degree distributions where row padding is cheap.
-    * ``sell``    — rmat8k-class graphs (``n * |E|`` beyond
+    * ``sell``    — on CPU, rmat8k-class graphs (``n * |E|`` beyond
       ``SELL_MIN_SCATTER_WORK``): scatter-free degree-bucketed gathers;
-      XLA:CPU's scatter collapses in this regime.
+      XLA:CPU's scatter collapses in this regime.  Not on other platforms:
+      the cliff is XLA:CPU's, and SELL's per-group gathers grow the program
+      with ``n / group_size``.
     * ``edges``   — everything else (small skewed / power-law graphs: a hub
       row would blow the ELL padding up to ``n * max_deg``).
 
@@ -269,8 +283,10 @@ def _heuristic_reason(graph, platform: Optional[str]) -> Tuple[str, str]:
     platform = platform or jax.default_backend()
     if graph.n <= DENSE_MAX_VERTICES:
         return "dense", f"n={graph.n} <= {DENSE_MAX_VERTICES} (tiny graph)"
-    if platform == "tpu" and graph.n >= BLOCKED_MIN_VERTICES:
-        return "blocked", f"tpu and n={graph.n} >= {BLOCKED_MIN_VERTICES}"
+    if platform == "tpu":
+        fits, why = blocked_operand_fit(graph)
+        if fits:
+            return "blocked", f"tpu and {why}"
     edges = max(graph.num_directed, 1)
     if DENSE_WORK_ADVANTAGE * edges >= graph.n**2:
         return "dense", (
@@ -283,9 +299,42 @@ def _heuristic_reason(graph, platform: Optional[str]) -> Tuple[str, str]:
             f"n*max_deg={graph.n * max_deg} <= {ELL_PAD_FACTOR}*|E| "
             "(flat degrees, padding bounded)"
         )
-    if graph.n * edges >= SELL_MIN_SCATTER_WORK:
+    if platform == "cpu" and graph.n * edges >= SELL_MIN_SCATTER_WORK:
         return "sell", (
             f"n*|E|={graph.n * edges} >= {SELL_MIN_SCATTER_WORK} "
             "(XLA:CPU scatter-cliff regime)"
         )
     return "edges", "skewed degrees below the scatter-cliff regime"
+
+
+def blocked_operand_fit(graph) -> Tuple[bool, str]:
+    """Whether the fused kernel's blocked-ELL operand fits — ``(ok, why)``.
+
+    Decided from the operand's own geometry (computed, never built): its
+    rows must fit the kernel's SMEM row tables, its padded bytes must stay
+    within :data:`BLOCKED_MAX_PADDING` of the edge bytes, and it must fit
+    the device's chunk budget.  The row bound is checked first from
+    ``|E| / capacity`` (a lower bound on the rows), so a graph far too big
+    never pays for the geometry's sort.
+    """
+    from repro.core.graph import BLOCKED_ROW_CAPACITY, blocked_ell_geometry
+    from repro.plan.cost import default_memory_budget_bytes
+
+    min_rows = -(-graph.num_directed // BLOCKED_ROW_CAPACITY)
+    if min_rows > BLOCKED_MAX_ROWS:
+        return False, f"needs >= {min_rows} operand rows > {BLOCKED_MAX_ROWS}"
+    geo = blocked_ell_geometry(graph)
+    if geo.n_rows + geo.n_blocks > BLOCKED_MAX_ROWS:
+        return False, f"{geo.n_rows} operand rows > {BLOCKED_MAX_ROWS}"
+    if geo.padding_factor > BLOCKED_MAX_PADDING:
+        return False, (
+            f"blocked operand {geo.operand_bytes} B is "
+            f"{geo.padding_factor:.2f}x its edge bytes > {BLOCKED_MAX_PADDING}x"
+        )
+    budget = default_memory_budget_bytes()
+    if geo.operand_bytes > budget:
+        return False, f"blocked operand {geo.operand_bytes} B > budget {budget} B"
+    return True, (
+        f"blocked operand {geo.n_rows} rows, {geo.padding_factor:.2f}x "
+        "its edge bytes"
+    )

@@ -7,8 +7,8 @@ axis is the vertex axis (lanes), the combinatorial color-set axis is tiled.
 
 Sparse structure (preprocessed host-side, ``repro.core.graph.build_blocked_ell``):
 vertices are tiled into blocks of ``block_size``; edges are grouped by
-(dst-block, src-block) pairs, padded to ``pair_capacity``, and pairs are
-sorted by destination block.  Per grid step the kernel holds one source tile
+(dst-block, src-block) pairs in rows of ``pair_capacity`` slots (a heavy
+pair spans consecutive rows), and rows are sorted by destination block.  Per grid step the kernel holds one source tile
 of ``M^T`` and one destination accumulator tile of ``B^T`` in VMEM.
 
 Two inner-loop strategies:
@@ -40,7 +40,12 @@ __all__ = ["spmm_blocked_kernel", "spmm_blocked_call"]
 
 
 def _mxu_chunk(m_blk, src_ids, dst_ids, valid, block_size, acc):
-    """acc += onehot(dst)ᵀ-scatter( onehot(src)-gather(m_blk) ) for one chunk."""
+    """acc += onehot(dst)ᵀ-scatter( onehot(src)-gather(m_blk) ) for one chunk.
+
+    Both products run at ``HIGHEST`` precision: the one-hot factors are
+    exact in any format, but the counts are not, and the MXU's default
+    single bf16 pass would round every gathered count to 8 mantissa bits.
+    """
     e = src_ids.shape[0]
     lanes = jax.lax.broadcasted_iota(jnp.int32, (e, block_size), 1)
     onehot_src = jnp.where(src_ids[:, None] == lanes, valid[:, None], 0.0)
@@ -49,12 +54,14 @@ def _mxu_chunk(m_blk, src_ids, dst_ids, valid, block_size, acc):
     gathered = jax.lax.dot_general(
         m_blk, onehot_src,
         dimension_numbers=(((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32,
     )
     # scatter: (C_tile, e) @ (e, bs) -> (C_tile, bs)
     return acc + jax.lax.dot_general(
         gathered, onehot_dst,
         dimension_numbers=(((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32,
     )
 
@@ -114,9 +121,9 @@ def spmm_blocked_call(
     pair_src_block: jnp.ndarray,   # (n_pairs,) int32
     pair_dst_block: jnp.ndarray,   # (n_pairs,) int32
     pair_is_first: jnp.ndarray,    # (n_pairs,) int32 — 1 at head of a dst-run
-    edge_dst_local: jnp.ndarray,   # (n_pairs, capacity) int32
-    edge_src_local: jnp.ndarray,   # (n_pairs, capacity) int32
-    edge_valid: jnp.ndarray,       # (n_pairs, capacity) f32
+    edge_dst_local: jnp.ndarray,   # (n_rows, 1, capacity) int32
+    edge_src_local: jnp.ndarray,   # (n_rows, 1, capacity) int32
+    edge_valid: jnp.ndarray,       # (n_rows, 1, capacity) f32
     *,
     block_size: int,
     col_tile: int = 128,
@@ -128,12 +135,15 @@ def spmm_blocked_call(
     ``C % col_tile == 0``, ``n_padded % block_size == 0``,
     ``capacity % edge_chunk == 0`` (pad host-side)."""
     c, n_padded = mt.shape
-    n_pairs, capacity = edge_dst_local.shape
+    n_pairs, _, capacity = edge_dst_local.shape
     if c % col_tile:
         raise ValueError(f"C={c} not a multiple of col_tile={col_tile}")
     if capacity % edge_chunk:
         raise ValueError(f"capacity={capacity} not a multiple of edge_chunk={edge_chunk}")
     grid = (c // col_tile, n_pairs)
+    # per-row edge slices: a squeezed leading axis keeps the block's last
+    # two dims (1, capacity) equal to the array's, as Mosaic requires
+    edge_spec = pl.BlockSpec((None, 1, capacity), lambda ci, p, *_: (p, 0, 0))
 
     kernel = functools.partial(
         spmm_blocked_kernel, block_size=block_size, edge_chunk=edge_chunk, mode=mode
@@ -143,9 +153,9 @@ def spmm_blocked_call(
         grid=grid,
         in_specs=[
             pl.BlockSpec((col_tile, block_size), lambda ci, p, sb, db, fi: (ci, sb[p])),
-            pl.BlockSpec((1, capacity), lambda ci, p, sb, db, fi: (p, 0)),
-            pl.BlockSpec((1, capacity), lambda ci, p, sb, db, fi: (p, 0)),
-            pl.BlockSpec((1, capacity), lambda ci, p, sb, db, fi: (p, 0)),
+            edge_spec,
+            edge_spec,
+            edge_spec,
         ],
         out_specs=pl.BlockSpec((col_tile, block_size), lambda ci, p, sb, db, fi: (ci, db[p])),
     )
@@ -154,4 +164,7 @@ def spmm_blocked_call(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((c, n_padded), mt.dtype),
         interpret=interpret,
-    )(pair_src_block, pair_dst_block, pair_is_first, mt, edge_dst_local, edge_src_local, edge_valid)
+    )(
+        pair_src_block, pair_dst_block, pair_is_first, mt,
+        edge_dst_local, edge_src_local, edge_valid,
+    )

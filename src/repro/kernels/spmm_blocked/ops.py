@@ -14,7 +14,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.graph import BlockedELL, Graph, build_blocked_ell
+from repro.core.graph import (BLOCKED_BLOCK_SIZE, BLOCKED_ROW_CAPACITY, Graph,
+                              build_blocked_ell)
 
 from .kernel import spmm_blocked_call
 
@@ -37,23 +38,28 @@ class BlockedSpmmOperand:
     edge_valid: jnp.ndarray
 
 
+# a pytree, so jitted programs take the arrays as arguments
+jax.tree_util.register_dataclass(
+    BlockedSpmmOperand,
+    data_fields=[
+        "pair_src_block", "pair_dst_block", "pair_is_first",
+        "edge_dst_local", "edge_src_local", "edge_valid",
+    ],
+    meta_fields=["n", "n_padded", "block_size", "edge_chunk"],
+)
+
+
 def prepare_operand(
-    graph: Graph, block_size: int = 256, edge_chunk: int = 256
+    graph: Graph, block_size: int = BLOCKED_BLOCK_SIZE, edge_chunk: int = BLOCKED_ROW_CAPACITY
 ) -> BlockedSpmmOperand:
-    """Blocked-ELL build + dummy pairs for empty destination blocks + padding."""
-    bell = build_blocked_ell(graph, block_size=block_size)
+    """Blocked-ELL build in ``edge_chunk``-slot rows + dummy rows for empty
+    destination blocks."""
+    bell = build_blocked_ell(graph, block_size=block_size, pair_capacity=edge_chunk)
     n_blocks = bell.n_blocks
     pair_dst = bell.pair_dst_block
     pair_src = bell.pair_src_block
     cap = bell.pair_capacity
-    cap_pad = ((cap + edge_chunk - 1) // edge_chunk) * edge_chunk
-
-    dst_loc = np.zeros((bell.n_pairs, cap_pad), dtype=np.int32)
-    src_loc = np.zeros((bell.n_pairs, cap_pad), dtype=np.int32)
-    valid = np.zeros((bell.n_pairs, cap_pad), dtype=np.float32)
-    dst_loc[:, :cap] = bell.edge_dst_local
-    src_loc[:, :cap] = bell.edge_src_local
-    valid[:, :cap] = bell.edge_valid
+    dst_loc, src_loc, valid = bell.edge_dst_local, bell.edge_src_local, bell.edge_valid
 
     # Every destination block must appear in >= 1 pair so its output tile is
     # zeroed (kernel writes only visited tiles).  Add all-invalid dummy pairs.
@@ -63,9 +69,9 @@ def prepare_operand(
     if missing.size:
         pair_dst = np.concatenate([pair_dst, missing])
         pair_src = np.concatenate([pair_src, np.zeros_like(missing)])
-        dst_loc = np.concatenate([dst_loc, np.zeros((missing.size, cap_pad), np.int32)])
-        src_loc = np.concatenate([src_loc, np.zeros((missing.size, cap_pad), np.int32)])
-        valid = np.concatenate([valid, np.zeros((missing.size, cap_pad), np.float32)])
+        dst_loc = np.concatenate([dst_loc, np.zeros((missing.size, cap), np.int32)])
+        src_loc = np.concatenate([src_loc, np.zeros((missing.size, cap), np.int32)])
+        valid = np.concatenate([valid, np.zeros((missing.size, cap), np.float32)])
         order = np.argsort(pair_dst, kind="stable")
         pair_dst, pair_src = pair_dst[order], pair_src[order]
         dst_loc, src_loc, valid = dst_loc[order], src_loc[order], valid[order]
@@ -81,9 +87,10 @@ def prepare_operand(
         pair_src_block=jnp.asarray(pair_src),
         pair_dst_block=jnp.asarray(pair_dst),
         pair_is_first=jnp.asarray(is_first),
-        edge_dst_local=jnp.asarray(dst_loc),
-        edge_src_local=jnp.asarray(src_loc),
-        edge_valid=jnp.asarray(valid),
+        # (n_rows, 1, capacity): the kernels' per-row block layout
+        edge_dst_local=jnp.asarray(dst_loc[:, None, :]),
+        edge_src_local=jnp.asarray(src_loc[:, None, :]),
+        edge_valid=jnp.asarray(valid[:, None, :]),
     )
 
 

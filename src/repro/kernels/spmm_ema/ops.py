@@ -4,8 +4,8 @@ Handles blocked-ELL preprocessing (+ the per-pair ``is_last`` run-tail
 flags), padding to kernel tile alignment, the row-major ``(n, C)`` <->
 transposed ``(C, n)`` conversion, and the engine's fused ``(n, B, C)``
 coloring-batch layout: a chunk of ``B`` colorings is folded into the
-*row* axis of the transposed operands with the split tables offset per
-coloring, so one kernel launch serves the whole chunk.
+*row* axis of the transposed operands, so one kernel launch serves the
+whole chunk.
 """
 
 from __future__ import annotations
@@ -16,10 +16,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.graph import Graph
+from repro.core.graph import BLOCKED_BLOCK_SIZE, BLOCKED_ROW_CAPACITY, Graph
 from repro.kernels.spmm_blocked.ops import BlockedSpmmOperand, prepare_operand
 
-from .kernel import spmm_ema_call
+from .kernel import pad8, spmm_ema_call
 
 __all__ = [
     "FusedSpmmEmaOperand",
@@ -34,11 +34,16 @@ class FusedSpmmEmaOperand:
     """Blocked-ELL arrays plus destination-run tail flags."""
 
     blocked: BlockedSpmmOperand
-    pair_is_last: jnp.ndarray  # (n_pairs,) int32
+    pair_is_last: jnp.ndarray  # (n_rows,) int32
+
+
+jax.tree_util.register_dataclass(
+    FusedSpmmEmaOperand, data_fields=["blocked", "pair_is_last"], meta_fields=[]
+)
 
 
 def prepare_fused_operand(
-    graph: Graph, block_size: int = 256, edge_chunk: int = 256
+    graph: Graph, block_size: int = BLOCKED_BLOCK_SIZE, edge_chunk: int = BLOCKED_ROW_CAPACITY
 ) -> FusedSpmmEmaOperand:
     """Blocked-ELL build + the ``is_last`` flag ending each dst-block run."""
     blocked = prepare_operand(graph, block_size=block_size, edge_chunk=edge_chunk)
@@ -47,10 +52,6 @@ def prepare_fused_operand(
     if pair_dst.shape[0] > 1:
         is_last[:-1] = (pair_dst[1:] != pair_dst[:-1]).astype(np.int32)
     return FusedSpmmEmaOperand(blocked=blocked, pair_is_last=jnp.asarray(is_last))
-
-
-def _pad_rows(x: np.ndarray, multiple: int = 8) -> int:
-    return ((x + multiple - 1) // multiple) * multiple
 
 
 def spmm_ema(
@@ -81,9 +82,9 @@ def spmm_ema_batched(
     """Fused stage over a chunk of ``B`` colorings -> ``(n, B, n_out)`` fp32.
 
     Each coloring's columns become an 8-row-aligned band of the transposed
-    operands, and the split tables are replicated per coloring with the
-    matching row offsets — the aggregate scratch stays one VMEM tile per
-    destination block for the whole chunk.
+    operands; one split table serves every band (the kernel derives each
+    coloring's row offset), and the aggregate scratch stays one VMEM tile
+    per destination block for the whole chunk.
     """
     blocked = operand.blocked
     n, bsz, c_p = m_p.shape
@@ -92,9 +93,9 @@ def spmm_ema_batched(
     idx_p = np.asarray(idx_p, dtype=np.int32)
     n_out, n_splits = idx_a.shape
 
-    cp_pad = _pad_rows(c_p)
-    ca_pad = _pad_rows(c_a)
-    nout_pad = _pad_rows(n_out)
+    cp_pad = pad8(c_p)
+    ca_pad = pad8(c_a)
+    nout_pad = pad8(n_out)
 
     def to_bands(m, c, c_pad):
         # (n, B, c) -> (B * c_pad, n_padded), coloring b in rows [b*c_pad, ...)
@@ -105,20 +106,13 @@ def spmm_ema_batched(
     mp_t = to_bands(m_p, c_p, cp_pad)
     ma_t = to_bands(m_a, c_a, ca_pad)
 
-    # Per-coloring table replication: rows [b*nout_pad, b*nout_pad + n_out)
-    # read M_a band b and aggregate band b (pad rows re-read row 0 of band 0;
-    # their output is sliced away below).
-    offs = np.arange(bsz, dtype=np.int32)
-    idx_a_full = np.zeros((bsz, nout_pad, n_splits), dtype=np.int32)
-    idx_p_full = np.zeros((bsz, nout_pad, n_splits), dtype=np.int32)
-    idx_a_full[:, :n_out, :] = idx_a[None] + (offs * ca_pad)[:, None, None]
-    idx_p_full[:, :n_out, :] = idx_p[None] + (offs * cp_pad)[:, None, None]
-
+    # one flat coloring-local table: the kernel offsets coloring b's reads
+    # into its own band from the output row index
     out_t = spmm_ema_call(
         mp_t,
         ma_t,
-        jnp.asarray(idx_a_full.reshape(bsz * nout_pad, n_splits)),
-        jnp.asarray(idx_p_full.reshape(bsz * nout_pad, n_splits)),
+        jnp.asarray(idx_a.reshape(-1)),
+        jnp.asarray(idx_p.reshape(-1)),
         blocked.pair_src_block,
         blocked.pair_dst_block,
         blocked.pair_is_first,
@@ -126,6 +120,9 @@ def spmm_ema_batched(
         blocked.edge_dst_local,
         blocked.edge_src_local,
         blocked.edge_valid,
+        n_colorings=bsz,
+        n_out=n_out,
+        n_splits=n_splits,
         block_size=blocked.block_size,
         edge_chunk=blocked.edge_chunk,
         interpret=interpret,

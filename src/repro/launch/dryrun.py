@@ -29,7 +29,6 @@ def run_cell(arch: str, shape_name: str, mesh_name: str, out_dir: str, probe: bo
     import jax
 
     from repro.configs.registry import shapes_for
-    from repro import compat
     from repro.launch.cells import build_cell
     from repro.launch.mesh import make_production_mesh
     from repro.launch.roofline import analyze_compiled
@@ -39,7 +38,7 @@ def run_cell(arch: str, shape_name: str, mesh_name: str, out_dir: str, probe: bo
     n_devices = mesh.devices.size
 
     t0 = time.monotonic()
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         cell = build_cell(arch, shape, mesh)
         jitted = jax.jit(
             cell.fn,
@@ -75,7 +74,7 @@ def run_cell(arch: str, shape_name: str, mesh_name: str, out_dir: str, probe: bo
         from repro.launch.probes import probe_costs
         from repro.launch.roofline import HBM_BW, ICI_BW, PEAK_FLOPS
 
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             corr = probe_costs(arch, shape, mesh)
         if corr is not None:
             rec["probe"] = corr

@@ -306,9 +306,7 @@ def _moe_apply_ep(params: Params, cfg: LMConfig, x: jnp.ndarray, act_spec) -> Tu
     """
     from jax.sharding import PartitionSpec as P
 
-    from .. import compat
-
-    mesh = compat.current_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     model_ax = "model"
     m_size = mesh.shape[model_ax]
     e, k = cfg.n_experts, cfg.moe_top_k
@@ -363,7 +361,7 @@ def _moe_apply_ep(params: Params, cfg: LMConfig, x: jnp.ndarray, act_spec) -> Tu
         return out.reshape(b_l, s_l, d), aux
 
     w_gate = params.get("w_gate", params["w_up"])
-    out, aux = compat.shard_map(
+    out, aux = jax.shard_map(
         local_fn,
         mesh=mesh,
         in_specs=(

@@ -237,14 +237,14 @@ def main(argv=None) -> int:
         plan = build_template_plan(group)
         _print_plan(plan)
         if graph is not None:
-            from repro.core.engine import DEFAULT_MEMORY_BUDGET_BYTES, CountingEngine
+            from repro.core.engine import CountingEngine
 
             eng = CountingEngine(
                 graph,
                 group,
                 backend=args.backend,
                 dtype_policy=args.dtype,
-                memory_budget_bytes=args.budget or DEFAULT_MEMORY_BUDGET_BYTES,
+                memory_budget_bytes=args.budget or None,
                 column_batch=args.column_batch,
                 chunk_size=args.chunk_size,
             )

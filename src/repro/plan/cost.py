@@ -32,6 +32,7 @@ import os
 from dataclasses import dataclass
 from typing import Dict, Optional
 
+import jax
 import jax.numpy as jnp
 
 __all__ = [
@@ -48,7 +49,11 @@ __all__ = [
     "mesh_link_bytes_per_us",
     "pick_chunk_size",
     "DEFAULT_MEMORY_BUDGET_BYTES",
+    "DEVICE_BUDGET_FRACTION",
+    "TPU_LANES",
+    "default_memory_budget_bytes",
     "MAX_CHUNK_SIZE",
+    "EDGE_CHUNK",
     "LOCAL_COLUMN_BATCH",
     "MESH_COLUMN_BATCH",
     "MESH_LINK_BYTES_PER_US",
@@ -61,12 +66,25 @@ __all__ = [
 
 logger = logging.getLogger("repro.plan")
 
-#: Default live-footprint budget for one chunk of colorings (bytes).  Sized
-#: for the CPU/laptop case; on real TPUs pass the per-core VMEM/HBM figure.
+#: Live-footprint budget for one chunk of colorings (bytes) on a device
+#: that reports no memory limit (the CPU backend).
 DEFAULT_MEMORY_BUDGET_BYTES = 32 * 1024 * 1024
+
+#: Share of a device's reported memory one chunk's live state may fill; the
+#: rest holds the graph operands, XLA temporaries and allocator slack.
+DEVICE_BUDGET_FRACTION = 0.5
+
+#: Minor-dimension tile width of TPU arrays: the DP state's column axis is
+#: the minor one of its ``(n, B, C)`` layout, so on a TPU every state and
+#: gathered slice occupies whole 128-lane tiles.
+TPU_LANES = 128
 
 #: Hard cap on colorings fused into one chunk (diminishing returns beyond).
 MAX_CHUNK_SIZE = 64
+
+#: Most edges the ``edges`` backend gathers in one step (longer edge lists
+#: are reduced chunk by chunk, bounding the gathered-message transient).
+EDGE_CHUNK = 1 << 21
 
 #: Default passive columns per fused SpMM+eMA slice on the local backends.
 #: Empirically (2-core XLA:CPU interleaved A/B on the rmat2k bench graphs):
@@ -117,6 +135,17 @@ MESH_LINK_ENV_VAR = "REPRO_MESH_LINK_BYTES_PER_US"
 #: term that keeps narrow stages on the blocking path, where one all-gather
 #: beats ``n_shards`` tiny hops.
 RING_STEP_OVERHEAD_US = 2.0
+
+
+def default_memory_budget_bytes() -> int:
+    """The chunk picker's default budget: :data:`DEVICE_BUDGET_FRACTION` of
+    the default device's ``memory_stats()["bytes_limit"]``, else
+    :data:`DEFAULT_MEMORY_BUDGET_BYTES` where the device reports no limit
+    (CPU)."""
+    limit = (jax.devices()[0].memory_stats() or {}).get("bytes_limit")
+    if not limit:
+        return DEFAULT_MEMORY_BUDGET_BYTES
+    return int(limit * DEVICE_BUDGET_FRACTION)
 
 
 def mesh_link_bytes_per_us() -> float:
@@ -316,7 +345,7 @@ def admission_estimate(
     *,
     store_dtype=jnp.float32,
     chunk_size: Optional[int] = None,
-    memory_budget_bytes: int = DEFAULT_MEMORY_BUDGET_BYTES,
+    memory_budget_bytes: Optional[int] = None,
     fusion_slack: Optional[float] = None,
 ) -> AdmissionEstimate:
     """Price a ``(graph, templates)`` query without building an engine.
@@ -335,6 +364,8 @@ def admission_estimate(
     cm = CostModel(plan, graph, store_dtype, fusion_slack=fusion_slack)
     resident = cm.resident_elements()
     per_coloring = cm.bytes_per_coloring(0, resident)
+    if memory_budget_bytes is None:
+        memory_budget_bytes = default_memory_budget_bytes()
     chunk = (
         int(chunk_size)
         if chunk_size
@@ -480,6 +511,8 @@ class CostModel:
         self.plan = plan
         self.graph = graph
         self.itemsize = jnp.dtype(store_dtype).itemsize
+        #: column-axis tile width of the device's arrays (1: no padding)
+        self.lanes = TPU_LANES if jax.default_backend() == "tpu" else 1
         self.fusion_slack = (
             load_fusion_slack() if fusion_slack is None else float(fusion_slack)
         )
@@ -491,8 +524,10 @@ class CostModel:
     # -- column-batch picking ------------------------------------------------
 
     def pick_local_column_batch(self) -> int:
-        """Fused-slice width for the single-device backends."""
-        return min(LOCAL_COLUMN_BATCH, self.plan.max_passive_columns)
+        """Fused-slice width for the single-device backends: at least one
+        lane tile on a TPU, where a narrower slice is padded to the tile's
+        width and pays for it in every sweep."""
+        return min(max(LOCAL_COLUMN_BATCH, self.lanes), self.plan.max_passive_columns)
 
     def pick_mesh_column_batch(self) -> int:
         """Columns per all-gather collective on the mesh target."""
@@ -510,7 +545,12 @@ class CostModel:
         """
         if getattr(self.plan, "has_bag_stages", False):
             return self.plan.peak_elements(self.graph.n)
+        if self.lanes > 1:
+            return self.graph.n * self.plan.padded_peak_columns(self.lanes)
         return self.graph.n * self.plan.peak_columns
+
+    def _lane_padded(self, columns: int) -> int:
+        return -(-columns // self.lanes) * self.lanes
 
     def transient_elements(
         self,
@@ -530,7 +570,13 @@ class CostModel:
         un-batched over the flattened state, so their slice can dominate.
         """
         g = self.graph
-        if target in ("edges", "custom"):
+        if target != "blocked":
+            # the blocked kernel keeps vertices on lanes; the XLA backends'
+            # slices put the column batch there
+            column_batch = self._lane_padded(column_batch)
+        if target == "edges":
+            out = (min(g.num_directed, EDGE_CHUNK) + g.n) * column_batch
+        elif target == "custom":
             out = (g.num_directed + g.n) * column_batch
         elif target == "ell":
             out = (g.n * max(g.max_degree(), 1) + g.n) * column_batch
@@ -606,8 +652,9 @@ class CostModel:
         self, n_padded: int, edges_per_shard: int, column_batch: int
     ) -> int:
         """Per-shard collective scratch: one all-gathered column batch
-        plus the per-shard edge message gather."""
-        return (n_padded + edges_per_shard) * column_batch
+        plus the per-shard edge message gather (one edge chunk of it)."""
+        edges = min(edges_per_shard, EDGE_CHUNK)
+        return (n_padded + edges) * self._lane_padded(column_batch)
 
     def mesh_resident_elements(
         self, rows_per_shard: int, column_batch: int, ema_mode: str = "streamed"
@@ -616,7 +663,8 @@ class CostModel:
         peak of padded M columns (memoized SpMM products count too in the
         non-streamed eMA modes)."""
         peak = self.plan.padded_peak_columns(
-            pad_unit=column_batch, track_products=(ema_mode != "streamed")
+            pad_unit=self._lane_padded(column_batch),
+            track_products=(ema_mode != "streamed"),
         )
         return rows_per_shard * peak
 

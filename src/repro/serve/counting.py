@@ -53,15 +53,11 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from repro.core.engine import (
-    DEFAULT_MEMORY_BUDGET_BYTES,
-    CountingEngine,
-    engine_cache_key,
-)
+from repro.core.engine import CountingEngine, engine_cache_key
 from repro.core.estimator import required_iterations
 from repro.core.graph import Graph
 from repro.core.templates import Template, connected_graphlets, get_template
-from repro.plan.cost import degradation_ladder
+from repro.plan.cost import default_memory_budget_bytes, degradation_ladder
 
 from .cache import EngineCache
 from .qos import Clock, SystemClock
@@ -222,7 +218,7 @@ class CountingService:
         backend: str = "auto",
         dtype_policy: Union[str, None] = "fp32",
         chunk_size: Optional[int] = None,
-        memory_budget_bytes: int = DEFAULT_MEMORY_BUDGET_BYTES,
+        memory_budget_bytes: Optional[int] = None,
         default_budget: int = DEFAULT_ADAPTIVE_BUDGET,
         min_iterations: int = DEFAULT_MIN_ITERATIONS,
         clock: Optional[Clock] = None,
@@ -233,7 +229,11 @@ class CountingService:
         self.backend = backend
         self.dtype_policy = dtype_policy
         self.chunk_size = chunk_size
-        self.memory_budget_bytes = int(memory_budget_bytes)
+        self.memory_budget_bytes = int(
+            default_memory_budget_bytes()
+            if memory_budget_bytes is None
+            else memory_budget_bytes
+        )
         self.default_budget = int(default_budget)
         self.min_iterations = int(min_iterations)
         self.clock = clock if clock is not None else SystemClock()

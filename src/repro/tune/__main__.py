@@ -22,6 +22,7 @@ import argparse
 import logging
 import sys
 
+from repro.compile_cache import enable_compile_cache
 from repro.core.graph import erdos_renyi_graph, grid_graph, rmat_graph
 from repro.core.templates import get_template
 
@@ -100,6 +101,7 @@ def main(argv=None) -> int:
         level=logging.DEBUG if args.verbose else logging.WARNING,
         format="%(name)s %(levelname)s %(message)s",
     )
+    enable_compile_cache()
     graph, graph_desc = _parse_graph(args.graph)
     templates = [get_template(name) for name in args.templates]
     print(f"tuning [{', '.join(t.name for t in templates)}] on {graph_desc}")

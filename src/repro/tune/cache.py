@@ -88,10 +88,12 @@ def canons_digest(canons) -> str:
 
 
 def device_kind() -> str:
-    """The hardware key measurements are valid for (``cpu``/``gpu``/``tpu``)."""
+    """The hardware key measurements are valid for: the chip model as JAX
+    reports it (``jax.devices()[0].device_kind``, e.g. ``TPU v5 lite``), so
+    a winner measured on one TPU generation never binds on another."""
     import jax
 
-    return str(jax.default_backend())
+    return str(jax.devices()[0].device_kind)
 
 
 def entry_key(graph_signature: str, canons, device: Optional[str] = None) -> str:

@@ -149,8 +149,8 @@ def tune(
     from repro.core.engine import CountingEngine, DtypePolicy
     from repro.exec.select import heuristic_backend
     from repro.plan.cost import (
-        DEFAULT_MEMORY_BUDGET_BYTES,
         CostModel,
+        default_memory_budget_bytes,
         load_backend_calibration,
     )
     from repro.plan.ir import build_template_plan
@@ -158,7 +158,7 @@ def tune(
     if measure_fn is None:
         measure_fn = measure_engine_us
     budget = (
-        DEFAULT_MEMORY_BUDGET_BYTES
+        default_memory_budget_bytes()
         if memory_budget_bytes is None
         else int(memory_budget_bytes)
     )

@@ -29,7 +29,7 @@ def test_distributed_count_matches_single_device():
     out = _run_child(
         r"""
 import jax, jax.numpy as jnp, numpy as np
-from repro.compat import set_mesh, shard_map
+from jax import set_mesh, shard_map
 from functools import partial
 from repro.core import (build_counting_plan, count_colorful_vectorized, get_template,
                         rmat_graph, spmm_edges)
@@ -58,7 +58,7 @@ def test_distributed_count_balance_degrees():
     out = _run_child(
         r"""
 import jax, jax.numpy as jnp, numpy as np
-from repro.compat import set_mesh, shard_map
+from jax import set_mesh, shard_map
 from functools import partial
 from repro.core import (build_counting_plan, count_colorful_vectorized, get_template,
                         rmat_graph, spmm_edges)
@@ -98,7 +98,7 @@ def test_streamed_ema_equals_baseline():
     out = _run_child(
         r"""
 import jax, jax.numpy as jnp, numpy as np
-from repro.compat import set_mesh, shard_map
+from jax import set_mesh, shard_map
 from repro.core import build_counting_plan, get_template, rmat_graph
 from repro.core.distributed import make_distributed_count_fn, shard_graph
 
@@ -129,7 +129,7 @@ def test_moe_ep_shard_map_matches_dense_path():
         r"""
 import dataclasses
 import jax, jax.numpy as jnp, numpy as np
-from repro.compat import set_mesh, shard_map
+from jax import set_mesh, shard_map
 from jax.sharding import PartitionSpec as P, NamedSharding
 from repro.configs import dbrx_132b
 from repro.models import layers as L
@@ -166,7 +166,7 @@ def test_compressed_psum_preserves_mean():
     out = _run_child(
         r"""
 import jax, jax.numpy as jnp, numpy as np
-from repro.compat import set_mesh, shard_map
+from jax import set_mesh, shard_map
 from functools import partial
 from jax.sharding import PartitionSpec as P
 from repro.train.compression import compressed_psum
@@ -196,14 +196,15 @@ def test_lm_pjit_train_step_on_mesh():
         r"""
 import dataclasses
 import jax, jax.numpy as jnp
-from repro.compat import set_mesh
-from jax.sharding import PartitionSpec as P, NamedSharding
+from jax import set_mesh
+from jax.sharding import AxisType, PartitionSpec as P, NamedSharding
 from repro.configs import granite_8b
 from repro.models import transformer as T
 from repro.train.optimizer import adamw_init, adamw_update
 
 cfg = dataclasses.replace(granite_8b.SMOKE_CONFIG, n_heads=8, n_kv_heads=4, scan_layers=True)
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+# GSPMD-propagated (Auto) axes: the model code carries no out_sharding hints
+mesh = jax.make_mesh((2, 4), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
 params = T.init_params(jax.random.PRNGKey(0), cfg)
 pspecs = T.param_pspecs(cfg, model_size=4)
 with set_mesh(mesh):

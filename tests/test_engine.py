@@ -50,8 +50,20 @@ def test_backend_tiny_graph_picks_dense():
     assert select_backend(grid_graph(8, 8), platform="cpu") == "dense"
 
 
-def test_backend_large_tpu_graph_picks_blocked():
-    assert select_backend(rmat_graph(8192, 40_000, seed=0), platform="tpu") == "blocked"
+@pytest.mark.parametrize(
+    "n,edges,picks_blocked",
+    [
+        # 256 block pairs, ~900 edges each: the operand is mostly edges
+        (4096, 200_000, True),
+        # scale-16 RMAT at edge factor 16: most block pairs hold a few
+        # edges, each padded to a full row — the XLA backends serve it
+        (1 << 16, 16 << 16, False),
+    ],
+)
+def test_backend_large_tpu_graph_picks_blocked(n, edges, picks_blocked):
+    g = rmat_graph(n, edges, seed=1)
+    name, reason = select_backend(g, platform="tpu", explain=True)
+    assert (name == "blocked") == picks_blocked, reason
 
 
 def test_engine_resolves_auto_backend():
@@ -80,6 +92,35 @@ def test_engine_raw_counts_match_reference(backend):
     assert got == pytest.approx(ref, rel=1e-5)
 
 
+@pytest.mark.parametrize("backend", ["edges", "mesh"])
+def test_edge_chunked_reduction_matches_reference(backend, monkeypatch):
+    """Edge lists longer than ``EDGE_CHUNK`` are reduced a chunk at a time,
+    the last chunk padded; with a chunk that does not divide ``|E|`` the
+    counts still match the reference DP."""
+    import repro.exec.local
+    import repro.plan.cost
+    from repro.kernels.spmm_blocked.ref import spmm_ref
+
+    g = rmat_graph(300, 1500, seed=2)
+    chunk = 700
+    assert g.num_directed > 2 * chunk and g.num_directed % chunk
+    monkeypatch.setattr(repro.exec.local, "EDGE_CHUNK", chunk)
+    monkeypatch.setattr(repro.plan.cost, "EDGE_CHUNK", chunk)
+    t = get_template("u6")
+    plan = build_counting_plan(t)
+    colors = np.random.default_rng(0).integers(0, t.k, size=g.n)
+    src, dst = jnp.asarray(g.src), jnp.asarray(g.dst)
+    ref = float(count_colorful_vectorized(plan, jnp.asarray(colors), partial(spmm_edges, src, dst, g.n)))
+    if backend == "mesh":
+        eng = CountingEngine(g, [t], mesh=jax.make_mesh((1,), ("dev",)))
+    else:
+        eng = CountingEngine(g, [t], backend=backend)
+        m = jnp.asarray(np.random.default_rng(1).standard_normal((g.n, 2, 3)), jnp.float32)
+        got_spmm = eng.backend_impl.spmm(m).reshape(g.n, 6)
+        assert np.allclose(got_spmm, spmm_ref(src, dst, g.n, m.reshape(g.n, 6)), rtol=1e-5, atol=1e-5)
+    assert float(eng.raw_counts(colors)[0]) == pytest.approx(ref, rel=1e-5)
+
+
 def test_engine_blocked_pallas_backend_matches_edges():
     g = rmat_graph(200, 800, seed=3)
     t = get_template("u5-2")
@@ -98,6 +139,22 @@ def test_engine_custom_spmm_fn():
     got = CountingEngine(g, [t], spmm_fn=custom, chunk_size=3).count_keys(keys)
     assert got.shape == ref.shape
     assert np.allclose(got, ref, rtol=1e-6)
+
+
+def test_estimates_beyond_fp32_range_stay_finite():
+    """Raw totals come back in fp32 and are scaled to estimates in float64:
+    an estimate past the fp32 maximum (u12 on a scale-20 RMAT) is finite."""
+    g = rmat_graph(300, 1500, seed=4)
+    t = get_template("u7")
+    keys = jax.random.split(jax.random.PRNGKey(2), 2)
+    base = CountingEngine(g, [t], backend="edges", chunk_size=2).count_keys(keys)
+    # every aggregation scaled by s: estimates scale by s**(k-1) to 1e39,
+    # the raw totals behind them (1e39 * 7!/7**7 * aut) stay inside fp32
+    s = float((1e39 / base.max()) ** (1 / (t.k - 1)))
+    edges = partial(spmm_edges, jnp.asarray(g.src), jnp.asarray(g.dst), g.n)
+    big = CountingEngine(g, [t], spmm_fn=lambda m: edges(m) * s, chunk_size=2).count_keys(keys)
+    assert np.all(np.isfinite(big)) and big.max() > float(np.finfo(np.float32).max)
+    assert np.allclose(big, base * s ** (t.k - 1), rtol=1e-4)
 
 
 # ---------------------------------------------------------------------------
@@ -238,6 +295,46 @@ def test_chunk_picker_scales_with_budget_and_is_capped():
     big_t = CountingEngine(g, [get_template("u7")])
     assert big_t.bytes_per_coloring() > small_t.bytes_per_coloring()
     assert big_t.chunk_size <= small_t.chunk_size
+
+
+@pytest.mark.parametrize("bytes_limit", [None, 16 << 30])
+def test_default_budget_follows_device_memory(monkeypatch, bytes_limit):
+    """The budget is a share of the device's reported memory; the CPU
+    constant applies only where the device reports none."""
+    from repro.plan import cost
+
+    class FakeDevice:
+        def memory_stats(self):
+            return None if bytes_limit is None else {"bytes_limit": bytes_limit}
+
+    monkeypatch.setattr(cost.jax, "devices", lambda: [FakeDevice()])
+    want = (
+        cost.DEFAULT_MEMORY_BUDGET_BYTES
+        if bytes_limit is None
+        else int(bytes_limit * cost.DEVICE_BUDGET_FRACTION)
+    )
+    assert cost.default_memory_budget_bytes() == want
+
+
+def test_compile_cache_dir_respects_env(monkeypatch):
+    """``JAX_COMPILATION_CACHE_DIR`` wins untouched; without it the entry
+    points' cache goes to a fixed directory inside the checkout."""
+    import os
+
+    from repro import compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/cache")
+        assert compile_cache.enable_compile_cache() == "/elsewhere/cache"
+        assert jax.config.jax_compilation_cache_dir == before
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        assert compile_cache.enable_compile_cache() == compile_cache.CACHE_DIR
+        assert jax.config.jax_compilation_cache_dir == compile_cache.CACHE_DIR
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert compile_cache.CACHE_DIR == os.path.join(repo, ".jax_cache")
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
 
 
 def test_peak_columns_liveness_bounds():

@@ -172,3 +172,62 @@ def test_full_dp_on_pallas_kernels(tname):
         count_colorful_vectorized(plan, jnp.asarray(colors), kern_spmm)
     )
     assert kern_total == pytest.approx(ref_total, rel=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# Blocked-ELL build: heavy pairs split into fixed-capacity rows
+# ---------------------------------------------------------------------------
+
+
+def _hub_graph():
+    """Skewed RMAT whose (block 0, block 0) pair holds far more edges than
+    one operand row: the hub pair spans several rows."""
+    return rmat_graph(300, 3000, seed=7, a=0.85, b=0.05, c=0.05)
+
+
+@pytest.mark.parametrize("capacity", [1, 3, 8, 64])
+def test_build_blocked_ell_small_capacity_keeps_every_edge(capacity):
+    from repro.core.graph import blocked_ell_geometry, build_blocked_ell
+
+    g = _hub_graph()
+    bell = build_blocked_ell(g, block_size=64, pair_capacity=capacity)
+    geo = blocked_ell_geometry(g, block_size=64, pair_capacity=capacity)
+    assert bell.pair_capacity == capacity
+    assert bell.n_pairs == geo.n_rows > geo.n_pairs  # some pair spilled
+    valid = bell.edge_valid > 0
+    rows = np.nonzero(valid)[0]
+    dst = bell.pair_dst_block[rows] * 64 + bell.edge_dst_local[valid]
+    src = bell.pair_src_block[rows] * 64 + bell.edge_src_local[valid]
+    got = np.sort(dst.astype(np.int64) * g.n + src)
+    want = np.sort(g.dst.astype(np.int64) * g.n + g.src)
+    assert np.array_equal(got, want)  # every edge exactly once
+    assert np.all(np.diff(bell.pair_dst_block) >= 0)  # rows sorted by dst block
+    assert bell.row_block_ptr[-1] == bell.n_pairs
+
+
+def test_split_hub_pair_matches_refs():
+    """Split rows of a hub pair feed one accumulator: the blocked SpMM and
+    the fused SpMM+eMA kernels (interpret mode) match their references."""
+    from repro.core.colorsets import binom
+    from repro.core.graph import blocked_ell_geometry
+    from repro.kernels.spmm_ema.ops import prepare_fused_operand, spmm_ema
+    from repro.kernels.spmm_ema.ref import spmm_ema_ref
+
+    g = _hub_graph()
+    geo = blocked_ell_geometry(g, block_size=128, pair_capacity=128)
+    assert geo.n_rows > geo.n_pairs
+    rng = np.random.default_rng(1)
+    src, dst = jnp.asarray(g.src), jnp.asarray(g.dst)
+
+    op = prepare_operand(g, block_size=128, edge_chunk=128)
+    m = jnp.asarray(rng.standard_normal((g.n, 24)).astype(np.float32))
+    out = spmm_blocked(op, m, interpret=True)
+    assert _rel_err(out, spmm_ref(src, dst, g.n, m)) < 1e-5
+
+    table = build_split_table(6, 4, 2)
+    fused = prepare_fused_operand(g, block_size=128, edge_chunk=128)
+    m_p = jnp.asarray(rng.standard_normal((g.n, binom(6, 2))).astype(np.float32))
+    m_a = jnp.asarray(rng.standard_normal((g.n, binom(6, 2))).astype(np.float32))
+    got = spmm_ema(fused, m_p, m_a, table.idx_a, table.idx_p, interpret=True)
+    ref = spmm_ema_ref(src, dst, g.n, m_p, m_a, jnp.asarray(table.idx_a), jnp.asarray(table.idx_p))
+    assert _rel_err(got, ref) < 1e-5

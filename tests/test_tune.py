@@ -4,6 +4,7 @@ backend execution equality, and the quarantine -> tuned-entry interop."""
 
 import json
 
+import jax
 import numpy as np
 import pytest
 
@@ -137,6 +138,18 @@ def test_cache_corrupt_or_stale_files_ignored(tmp_path, content, caplog):
     # never raises on the resolution hot path either
     assert consult("sig", [[0]], device="cpu", path=path) is None
     assert load_calibration(path) == {}
+
+
+def test_cache_entries_keyed_by_device_kind():
+    """Entries carry the chip model, not the platform: a winner measured on
+    one TPU generation must not bind on another."""
+    from repro.tune.cache import device_kind
+
+    assert device_kind() == jax.devices()[0].device_kind
+    assert entry_key("sig-a", [[0, 1]]).endswith("|" + device_kind())
+    assert entry_key("sig-a", [[0, 1]], "TPU v5 lite") != entry_key(
+        "sig-a", [[0, 1]], "TPU v4"
+    )
 
 
 def test_cache_malformed_entry_ignored(tmp_path):
