@@ -27,6 +27,8 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from repro import tracing
+
 from .colorsets import (
     SplitTable,
     UnionSplitTable,
@@ -355,7 +357,8 @@ def fused_aggregate_ema_grouped(
     the ungrouped execution, so results are bit-exact with it.
     """
     n, bsz = m_p.shape[0], m_p.shape[1]
-    outs = [jnp.zeros((n, bsz, n_out), accum_dtype) for _, _, n_out in stages]
+    with tracing.scope("ema"):
+        outs = [jnp.zeros((n, bsz, n_out), accum_dtype) for _, _, n_out in stages]
     # Union of the stages' bucketed batches, keyed by batch start column.
     # Stages share C_p and the bucketing width, so equal `lo` => equal slice.
     sweep: Dict[int, Tuple[int, List[Tuple[int, Tuple]]]] = {}
@@ -372,12 +375,14 @@ def fused_aggregate_ema_grouped(
             sweep[lo] = (width, users)
     for lo in sorted(sweep):
         width, users = sweep[lo]
-        cols = jax.lax.slice_in_dim(m_p, lo, lo + width, axis=2)
-        bcol = spmm_fn(cols)  # (n, B, width) — the only aggregate transient
-        for s_idx, (idx_a, idx_p, valid) in users:
-            outs[s_idx] = _fused_batch_apply(
-                outs[s_idx], stages[s_idx][0], bcol, idx_a, idx_p, valid, accum_dtype
-            )
+        with tracing.scope("spmm"):
+            cols = jax.lax.slice_in_dim(m_p, lo, lo + width, axis=2)
+            bcol = spmm_fn(cols)  # (n, B, width) — the only aggregate transient
+        with tracing.scope("ema"):
+            for s_idx, (idx_a, idx_p, valid) in users:
+                outs[s_idx] = _fused_batch_apply(
+                    outs[s_idx], stages[s_idx][0], bcol, idx_a, idx_p, valid, accum_dtype
+                )
     return outs
 
 

@@ -45,6 +45,8 @@ import numpy as np
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
+from repro import tracing
+
 from .colorsets import binom
 from .counting import CountingPlan, _ema_apply_fused, schedule_liveness
 from .graph import Graph
@@ -196,11 +198,12 @@ def _compressed_gather(x, axes, gather_dtype):
     collective (observed on XLA:CPU: convert(bf16)->gather->convert(f32) gets
     folded back to an f32 gather, rounding values without saving bytes).
     """
-    if gather_dtype is None:
-        return jax.lax.all_gather(x, axes, axis=0, tiled=True)
-    payload = jax.lax.optimization_barrier(x.astype(gather_dtype))
-    full = jax.lax.all_gather(payload, axes, axis=0, tiled=True)
-    return jax.lax.optimization_barrier(full).astype(jnp.float32)
+    with tracing.scope("gather"):
+        if gather_dtype is None:
+            return jax.lax.all_gather(x, axes, axis=0, tiled=True)
+        payload = jax.lax.optimization_barrier(x.astype(gather_dtype))
+        full = jax.lax.all_gather(payload, axes, axis=0, tiled=True)
+        return jax.lax.optimization_barrier(full).astype(jnp.float32)
 
 
 def _pvary_missing(x, axes):
@@ -445,6 +448,13 @@ def make_batched_count_fn(
         # the plan's liveness schedule: only the non-streamed eMA modes
         # memoize aggregate products, so they free against that variant
         free_at = plan_ir.liveness(track_products=track_products)
+    # stages are named by their shared-passive exec group's leader, as the
+    # local engine (which runs each group as one sweep) names them
+    leader_of = {
+        member: leader
+        for leader, members in (plan_ir.exec_groups.items() if plan_ir is not None else ())
+        for member in members
+    }
 
     # --- split tables: built once, de-duplicated by (k, m, m_a).
     tables_dev = {}
@@ -557,7 +567,8 @@ def make_batched_count_fn(
         def block(d, owner):
             cur = state["cur"]
             if d + 1 < n_shards:  # prefetch the next slice NOW
-                state["cur"] = jax.lax.ppermute(cur, ring, perm)
+                with tracing.scope("ring"):
+                    state["cur"] = jax.lax.ppermute(cur, ring, perm)
             if gather_dtype is not None:
                 return jax.lax.optimization_barrier(cur).astype(jnp.float32)
             return cur
@@ -575,25 +586,26 @@ def make_batched_count_fn(
         ent_out, ent_ia, ent_ip, ent_valid = stream_tbl
 
         def body(b_idx, m_s):
-            cols = jax.lax.dynamic_slice(m_p, (0, 0, b_idx * cb), (rows, bsz, cb))
-            if mode == "pipelined":
-                bcol = ring_spmm(cols, src, dst_local, edge_mask)
-            elif bucket_fold:
-                # single-axis bucketed blocking: one all-gather, then the
-                # SAME per-bucket fold the ring runs — this is what makes
-                # blocking and pipelined engines bit-exact A/B arms
-                full = _compressed_gather(cols, axes, gather_dtype)
-                bcol = _bucket_partials(
-                    lambda d, owner: jax.lax.dynamic_slice(
-                        full, (owner * rows, 0, 0), (rows, bsz, cb)
-                    ),
-                    src, dst_local, edge_mask, bsz, cb,
-                )
-            else:
-                full = _compressed_gather(cols, axes, gather_dtype)
-                bcol = _gather_segment_sum(
-                    full, src, dst_local, edge_mask, rows, accum_dtype, axes
-                )
+            with tracing.scope("spmm"):
+                cols = jax.lax.dynamic_slice(m_p, (0, 0, b_idx * cb), (rows, bsz, cb))
+                if mode == "pipelined":
+                    bcol = ring_spmm(cols, src, dst_local, edge_mask)
+                elif bucket_fold:
+                    # single-axis bucketed blocking: one all-gather, then the
+                    # SAME per-bucket fold the ring runs — this is what makes
+                    # blocking and pipelined engines bit-exact A/B arms
+                    full = _compressed_gather(cols, axes, gather_dtype)
+                    bcol = _bucket_partials(
+                        lambda d, owner: jax.lax.dynamic_slice(
+                            full, (owner * rows, 0, 0), (rows, bsz, cb)
+                        ),
+                        src, dst_local, edge_mask, bsz, cb,
+                    )
+                else:
+                    full = _compressed_gather(cols, axes, gather_dtype)
+                    bcol = _gather_segment_sum(
+                        full, src, dst_local, edge_mask, rows, accum_dtype, axes
+                    )
             row = [
                 jax.lax.dynamic_index_in_dim(a, b_idx, keepdims=False)
                 for a in (ent_out, ent_ia, ent_ip, ent_valid)
@@ -610,11 +622,13 @@ def make_batched_count_fn(
                 )
                 return m_s.at[:, :, eo].add(prod)
 
-            return jax.lax.fori_loop(0, cap // width, fold, m_s)
+            with tracing.scope("ema"):
+                return jax.lax.fori_loop(0, cap // width, fold, m_s)
 
         cap = ent_out.shape[1]
         width = min(cap, EMA_ENTRY_CHUNK)
-        init = _pvary_missing(jnp.zeros((rows, bsz, n_out), accum_dtype), axes)
+        with tracing.scope("ema"):
+            init = _pvary_missing(jnp.zeros((rows, bsz, n_out), accum_dtype), axes)
         return jax.lax.fori_loop(0, n_batches, body, init)
 
     def ema_loop(m_a, b, idx_a, idx_p):
@@ -639,7 +653,8 @@ def make_batched_count_fn(
                 else:
                     slots.pop(key, None)
 
-        leaf = pad_c(jax.nn.one_hot(colors.T, k, dtype=store_dtype))  # (rows, B, k_pad)
+        with tracing.scope("colors"):
+            leaf = pad_c(jax.nn.one_hot(colors.T, k, dtype=store_dtype))  # (rows, B, k_pad)
         executed = set()
         slots = {}
         prods = {}
@@ -657,41 +672,49 @@ def make_batched_count_fn(
                 else:
                     m_a, m_p = slots[pc[sub.active]], slots[pc[sub.passive]]
                     tkey = stage_table_key[(p_idx, i)]
-                    if ema_mode == "streamed":
-                        m_s = spmm_ema_streamed(
-                            m_p, m_a, src, dst_local, edge_mask,
-                            plan.tables[i].n_out, tables[tkey],
-                            mode=comm_schedule.get((p_idx, i), comm_mode),
-                        )
-                    else:
-                        p_key = pc[sub.passive]
-                        if p_key not in prods:
-                            prods[p_key] = spmm_batched(m_p, src, dst_local, edge_mask)
-                        b = prods[p_key]
-                        idx_a, idx_p = tables[tkey]
-                        if ema_mode == "vectorized":
-                            # probe mode: single gather-FMA einsum (no
-                            # fori_loop) so the split-axis work is visible to
-                            # cost_analysis
-                            m_s = jnp.einsum(
-                                "rbos,rbos->rbo",
-                                jnp.take(m_a, idx_a, axis=2).astype(accum_dtype),
-                                jnp.take(b, idx_p, axis=2),
-                                precision=jax.lax.Precision.HIGHEST,
+                    lp, li = leader_of.get((p_idx, i), (p_idx, i))
+                    with tracing.scope(tracing.stage(lp, li, plans[lp].partition.subs[li].size)):
+                        if ema_mode == "streamed":
+                            m_s = spmm_ema_streamed(
+                                m_p, m_a, src, dst_local, edge_mask,
+                                plan.tables[i].n_out, tables[tkey],
+                                mode=comm_schedule.get((p_idx, i), comm_mode),
                             )
                         else:
-                            m_s = ema_loop(m_a, b, idx_a, idx_p)
-                    slots[ckey] = pad_c(m_s.astype(store_dtype))
+                            p_key = pc[sub.passive]
+                            if p_key not in prods:
+                                with tracing.scope("spmm"):
+                                    prods[p_key] = spmm_batched(m_p, src, dst_local, edge_mask)
+                            b = prods[p_key]
+                            idx_a, idx_p = tables[tkey]
+                            with tracing.scope("ema"):
+                                if ema_mode == "vectorized":
+                                    # probe mode: single gather-FMA einsum (no
+                                    # fori_loop) so the split-axis work is
+                                    # visible to cost_analysis
+                                    m_s = jnp.einsum(
+                                        "rbos,rbos->rbo",
+                                        jnp.take(m_a, idx_a, axis=2).astype(accum_dtype),
+                                        jnp.take(b, idx_p, axis=2),
+                                        precision=jax.lax.Precision.HIGHEST,
+                                    )
+                                else:
+                                    m_s = ema_loop(m_a, b, idx_a, idx_p)
+                        with tracing.scope("ema"):
+                            slots[ckey] = pad_c(m_s.astype(store_dtype))
                 free(pos, slots, prods)
                 pos += 1
-            root = slots[pc[plan.partition.root_index]].astype(accum_dtype)
-            # reduce color sets first, then vertices, then shards: the local
-            # order matches the single-host engine's per-coloring reduction
-            total_local = root.sum(axis=2).sum(axis=0)
-            totals.append(jax.lax.psum(total_local, axes))  # (B,), replicated
+            with tracing.scope("root"):
+                root = slots[pc[plan.partition.root_index]].astype(accum_dtype)
+                # reduce color sets first, then vertices, then shards: the
+                # local order matches the single-host engine's per-coloring
+                # reduction
+                total_local = root.sum(axis=2).sum(axis=0)
+                totals.append(jax.lax.psum(total_local, axes))  # (B,), replicated
             free(pos, slots, prods)
             pos += 1
-        return jnp.stack(totals, axis=1).astype(jnp.float32)  # (B, T)
+        with tracing.scope("root"):
+            return jnp.stack(totals, axis=1).astype(jnp.float32)  # (B, T)
 
     sharded = P(axes)
     mapped = jax.shard_map(

@@ -37,6 +37,7 @@ import jax.numpy as jnp
 
 # Submodule imports only (repro.exec/.plan's __init__ import repro.core
 # right back); every re-exported compat name is listed in __all__.
+from repro import tracing
 from repro.exec.base import EngineBackend, StageTables, make_backend
 from repro.exec.local import SELL_GROUP_SIZE
 from repro.exec.mesh import MeshBackend
@@ -596,11 +597,10 @@ class CountingEngine:
         iters = int(iterations) if iterations else self.chunk_size
         chunk = max(1, min(self.chunk_size, iters))
         n_chunks = -(-iters // chunk)
-        keys = jnp.zeros((n_chunks, chunk, 2), jnp.uint32)
         predicted = float(self.predicted_peak_bytes())
         actual: Optional[float] = None
         try:
-            compiled = self._get_run_fn().lower(keys).compile()
+            compiled = self._compiled(self._get_run_fn(), (n_chunks, chunk, 2))
             analysis = compiled.memory_analysis()
             actual = float(analysis.temp_size_in_bytes)
         except (AttributeError, NotImplementedError, TypeError) as exc:  # pragma: no cover
@@ -612,6 +612,24 @@ class CountingEngine:
             "actual_temp_bytes": actual,
             "ratio": (predicted / actual) if actual else None,
         }
+
+    def launch_scopes(self) -> Dict[str, str]:
+        """HLO operation name -> ``s2v`` scope path (``stage0_2_m3/spmm``,
+        ``colors``, ...) of the program :meth:`count_keys_chunk` runs.
+
+        Compiles that program at the chunk shape from shapes alone (a warm
+        engine's launch is found in JAX's compile caches), so a profiler
+        trace of the launches can be read by DP stage and layer.  For
+        after a measured window and for operators: it never runs on the
+        launch path.
+        """
+        compiled = self._compiled(self._get_chunk_fn(), (self.chunk_size, 2))
+        return tracing.op_scopes(compiled.as_text())
+
+    def _compiled(self, fn, keys_shape: Tuple[int, ...]):
+        """Lower and compile ``fn`` (a backend ``jit``) for uint32 PRNG
+        keys of ``keys_shape``, with no data."""
+        return fn.lower(jax.ShapeDtypeStruct(keys_shape, jnp.uint32)).compile()
 
     # ------------------------------------------------------------------
     # Public API
@@ -657,32 +675,41 @@ class CountingEngine:
         hook would only run at trace time, so a warm engine would never
         see it.  ``launch`` covers every backend; ``collective`` only the
         backends that declare it (``EngineBackend.fault_sites``).
+
+        The call is the host span ``s2v.count_keys_chunk``, holding
+        ``s2v.dispatch``, ``s2v.fetch`` and ``s2v.scale``
+        (:mod:`repro.tracing`).
         """
-        keys = jnp.asarray(keys)
-        m = int(keys.shape[0])
-        if m == 0:
-            return np.zeros((0, len(self.templates)), np.float64)
-        if m > self.chunk_size:
-            raise ValueError(
-                f"increment of {m} keys exceeds chunk_size={self.chunk_size}; "
-                "split it (count_keys handles multi-chunk runs)"
-            )
-        _faults.maybe_fail("launch", ctx=f"backend={self.backend}")
-        if "collective" in getattr(self.backend_impl, "fault_sites", ()):
-            # the pipelined mesh path crosses the collective seam once per
-            # ring step (blocking: once per launch) — the injection site
-            # fires with matching multiplicity so a seeded fault plan sees
-            # every dispatch
-            for step in range(getattr(self.backend_impl, "collective_dispatches", 1)):
-                _faults.maybe_fail(
-                    "collective", ctx=f"backend={self.backend} step={step}"
-                )
-        pad = self.chunk_size - m
-        if pad:
-            keys = jnp.concatenate([keys, keys[-1:].repeat(pad, axis=0)], axis=0)
-        vals = self._get_chunk_fn()(keys)
-        out = np.asarray(vals, dtype=np.float64)[:m] * self._norm_factors
-        return _faults.corrupt_result("launch", out, ctx=f"backend={self.backend}")
+        with tracing.span("count_keys_chunk"):
+            with tracing.span("dispatch"):
+                keys = jnp.asarray(keys)
+                m = int(keys.shape[0])
+                if m == 0:
+                    return np.zeros((0, len(self.templates)), np.float64)
+                if m > self.chunk_size:
+                    raise ValueError(
+                        f"increment of {m} keys exceeds chunk_size={self.chunk_size}; "
+                        "split it (count_keys handles multi-chunk runs)"
+                    )
+                _faults.maybe_fail("launch", ctx=f"backend={self.backend}")
+                if "collective" in getattr(self.backend_impl, "fault_sites", ()):
+                    # the pipelined mesh path crosses the collective seam
+                    # once per ring step (blocking: once per launch) — the
+                    # injection site fires with matching multiplicity so a
+                    # seeded fault plan sees every dispatch
+                    for step in range(getattr(self.backend_impl, "collective_dispatches", 1)):
+                        _faults.maybe_fail(
+                            "collective", ctx=f"backend={self.backend} step={step}"
+                        )
+                pad = self.chunk_size - m
+                if pad:
+                    keys = jnp.concatenate([keys, keys[-1:].repeat(pad, axis=0)], axis=0)
+                vals = self._get_chunk_fn()(keys)
+            with tracing.span("fetch"):
+                vals = np.asarray(vals)
+            with tracing.span("scale"):
+                out = vals.astype(np.float64)[:m] * self._norm_factors
+                return _faults.corrupt_result("launch", out, ctx=f"backend={self.backend}")
 
     def count_keys(self, keys) -> np.ndarray:
         """Normalized per-iteration estimates for explicit PRNG keys.

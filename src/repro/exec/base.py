@@ -34,6 +34,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from repro import tracing
 from repro.core.colorsets import bucketed_split_entries
 from repro.plan.cost import MAX_CHUNK_SIZE
 
@@ -271,9 +272,10 @@ class EngineBackend:
         on every backend, mesh included.
         """
         eng = self.engine
-        colors = jax.vmap(
-            lambda key: jax.random.randint(key, (eng.graph.n,), 0, eng.k)
-        )(keys_chunk)
+        with tracing.scope("colors"):
+            colors = jax.vmap(
+                lambda key: jax.random.randint(key, (eng.graph.n,), 0, eng.k)
+            )(keys_chunk)
         return self.counts_for_colors(colors)
 
     def make_run_fn(self) -> Callable:

@@ -18,6 +18,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from repro import tracing
 from repro.core.counting import fused_aggregate_ema_grouped
 from repro.core.graph import BLOCKED_BLOCK_SIZE, build_sell
 from repro.plan.cost import EDGE_CHUNK
@@ -122,7 +123,8 @@ class LocalBackend(EngineBackend):
         eng = self.engine
         ir = eng.plan_ir
         pol = eng.policy
-        leaf = jax.nn.one_hot(colors.T, eng.k, dtype=pol.store_dtype)  # (n, B, k)
+        with tracing.scope("colors"):
+            leaf = jax.nn.one_hot(colors.T, eng.k, dtype=pol.store_dtype)  # (n, B, k)
         free_at = ir.free_at
         slots: Dict[str, jnp.ndarray] = {}
         totals = []
@@ -140,16 +142,18 @@ class LocalBackend(EngineBackend):
                     if op.kind == "leaf":
                         slots[key] = leaf
                     elif key not in slots:
-                        slots[key] = self._run_bag_op(
-                            cplan, canons, p_idx, i, op, leaf, slots
-                        ).astype(pol.store_dtype)
+                        with tracing.scope(tracing.stage(p_idx, i, op.m)):
+                            state = self._run_bag_op(cplan, canons, p_idx, i, op, leaf, slots)
+                            with tracing.scope("ema"):
+                                slots[key] = state.astype(pol.store_dtype)
                     for dead in free_at.get(pos, ()):
                         slots.pop(dead, None)
                     pos += 1
                 # final op has no vertex axes: state is (B, 1) — the single
                 # C(k, k) colorset column holds the full colorful total
-                root = slots[canons[len(ops) - 1]].astype(pol.accum_dtype)
-                totals.append(root.sum(axis=-1).astype(jnp.float32))
+                with tracing.scope("root"):
+                    root = slots[canons[len(ops) - 1]].astype(pol.accum_dtype)
+                    totals.append(root.sum(axis=-1).astype(jnp.float32))
                 for dead in free_at.get(pos, ()):
                     slots.pop(dead, None)
                 pos += 1
@@ -175,23 +179,28 @@ class LocalBackend(EngineBackend):
                                 self.stage_tables[(q, j)],
                             )
                         )
-                    outs = self._group_aggregate(
-                        (p_idx, i), slots[canons[sub.passive]], stage_inputs
-                    )
-                    for (q, j), m_s in zip(members, outs):
-                        slots[ir.canons[q][j]] = m_s.astype(pol.store_dtype)
+                    with tracing.scope(tracing.stage(p_idx, i, sub.size)):
+                        outs = self._group_aggregate(
+                            (p_idx, i), slots[canons[sub.passive]], stage_inputs
+                        )
+                        with tracing.scope("ema"):
+                            for (q, j), m_s in zip(members, outs):
+                                slots[ir.canons[q][j]] = m_s.astype(pol.store_dtype)
                 # else: already produced early as a member of a prior group
                 for dead in free_at.get(pos, ()):
                     slots.pop(dead, None)
                 pos += 1
-            root = slots[canons[cplan.partition.root_index]].astype(pol.accum_dtype)
-            # reduce color sets first, then vertices: the per-coloring order
-            # is independent of the batch size (bit-exact across chunkings)
-            totals.append(root.sum(axis=2).sum(axis=0).astype(jnp.float32))
+            with tracing.scope("root"):
+                root = slots[canons[cplan.partition.root_index]].astype(pol.accum_dtype)
+                # reduce color sets first, then vertices: the per-coloring
+                # order is independent of the batch size (bit-exact across
+                # chunkings)
+                totals.append(root.sum(axis=2).sum(axis=0).astype(jnp.float32))
             for dead in free_at.get(pos, ()):
                 slots.pop(dead, None)
             pos += 1
-        return jnp.stack(totals, axis=1)  # (B, T)
+        with tracing.scope("root"):
+            return jnp.stack(totals, axis=1)  # (B, T)
 
     # -- bag-program execution ------------------------------------------------
 
@@ -237,7 +246,8 @@ class LocalBackend(EngineBackend):
             state = jnp.moveaxis(state, ax, 0)
             rest = state.shape[1:]
             flat = state.reshape(n, -1, state.shape[-1])
-            state = self._spmm_counted(flat).reshape((n,) + rest)
+            with tracing.scope("spmm"):
+                state = self._spmm_counted(flat).reshape((n,) + rest)
             axes_now.pop(ax)
             axes_now = [w] + axes_now
         else:
@@ -265,12 +275,13 @@ class LocalBackend(EngineBackend):
             gp = jnp.take(state, ip, axis=-1).astype(accum)
             return acc + la * gp
 
-        out = jax.lax.fori_loop(
-            0,
-            tables.n_terms,
-            body,
-            jnp.zeros(state.shape[:-1] + (tables.n_out,), accum),
-        )
+        with tracing.scope("ema"):
+            out = jax.lax.fori_loop(
+                0,
+                tables.n_terms,
+                body,
+                jnp.zeros(state.shape[:-1] + (tables.n_out,), accum),
+            )
         out, axes_now = self._bag_forget(out, axes_now, op.forget_vertices)
         # Restore sorted-axis order (the new vertex axis sits in front).
         order = sorted(range(len(axes_now)), key=lambda idx: axes_now[idx])
@@ -294,12 +305,13 @@ class LocalBackend(EngineBackend):
             g2 = jnp.take(s2, ip, axis=-1).astype(accum)
             return acc + g1 * g2
 
-        return jax.lax.fori_loop(
-            0,
-            tables.n_terms,
-            body,
-            jnp.zeros(s1.shape[:-1] + (tables.n_out,), accum),
-        )
+        with tracing.scope("ema"):
+            return jax.lax.fori_loop(
+                0,
+                tables.n_terms,
+                body,
+                jnp.zeros(s1.shape[:-1] + (tables.n_out,), accum),
+            )
 
 
 class EdgesBackend(LocalBackend):
@@ -475,14 +487,15 @@ class BlockedEllBackend(LocalBackend):
         from repro.kernels.spmm_ema.ops import spmm_ema_batched
 
         self.engine.counters["passive_aggregations"] += 1
-        return spmm_ema_batched(
-            self._fused_op,
-            m_p,
-            m_a,
-            tables.idx_a_host,
-            tables.idx_p_host,
-            interpret=self.engine.interpret,
-        ).astype(self.engine.policy.accum_dtype)
+        with tracing.scope("spmm_ema"):
+            return spmm_ema_batched(
+                self._fused_op,
+                m_p,
+                m_a,
+                tables.idx_a_host,
+                tables.idx_p_host,
+                interpret=self.engine.interpret,
+            ).astype(self.engine.policy.accum_dtype)
 
     def aggregate_ema_grouped(self, m_p, stage_inputs):
         # the Pallas kernel fuses SpMM+eMA per stage inside one launch; a

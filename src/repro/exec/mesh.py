@@ -36,6 +36,8 @@ import jax.numpy as jnp
 from jax.sharding import AxisType, Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
+from repro import tracing
+
 from .base import EngineBackend
 from .select import mesh_comm_mode
 
@@ -255,13 +257,14 @@ class MeshBackend(EngineBackend):
 
     def counts_for_colors(self, colors: jnp.ndarray) -> jnp.ndarray:
         colors = jnp.asarray(colors)
-        if self._perm is not None:
-            padded = jnp.zeros((colors.shape[0], self.sharded.n_padded), colors.dtype)
-            colors = padded.at[:, self._perm].set(colors)
-        else:
-            pad = self.sharded.n_padded - colors.shape[1]
-            if pad:
-                colors = jnp.pad(colors, ((0, 0), (0, pad)))
+        with tracing.scope("colors"):
+            if self._perm is not None:
+                padded = jnp.zeros((colors.shape[0], self.sharded.n_padded), colors.dtype)
+                colors = padded.at[:, self._perm].set(colors)
+            else:
+                pad = self.sharded.n_padded - colors.shape[1]
+                if pad:
+                    colors = jnp.pad(colors, ((0, 0), (0, pad)))
         return self._count_fn(colors, self._src, self._dst_local, self._edge_mask)
 
     # -- memory-model geometry (per shard!) ----------------------------------
