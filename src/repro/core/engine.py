@@ -388,10 +388,16 @@ class CountingEngine:
 
         # Observability counters, Python-level: ``trace_count`` bumps once
         # per jit trace (== compilation), ``passive_aggregations`` once per
-        # traced aggregation launch — a warm engine replaying compiled
-        # programs holds steady on both.
+        # traced aggregation launch, ``lane_padded_aggregations`` once per
+        # traced ``edges`` aggregation whose operand was zero-padded to the
+        # lane width and ``lane_pad_columns`` by the columns it added — a
+        # warm engine replaying compiled programs holds steady on all.
         self.trace_count = 0
-        self.counters: Dict[str, int] = {"passive_aggregations": 0}
+        self.counters: Dict[str, int] = {
+            "passive_aggregations": 0,
+            "lane_padded_aggregations": 0,
+            "lane_pad_columns": 0,
+        }
 
         # --- layer 3: bind the plan to devices.
         self.backend_impl: EngineBackend = make_backend(

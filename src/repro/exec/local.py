@@ -321,6 +321,15 @@ class EdgesBackend(LocalBackend):
     at a time, so the gathered ``(edges, B, c)`` messages never exist for
     the whole graph: on a TPU their small minor dims pad to full 128-lane
     tiles, and a deployment-size edge list would not fit the device.
+
+    The gather and the scatter-add run on one minor dim: ``(B, c)``
+    flattened and zero-padded to a multiple of the lane width
+    (``engine.cost.lanes``: 128 on a TPU, 1 elsewhere, so no padding
+    there).  Left as they come, XLA lays a narrow slice out vertex-minor
+    and a ``(2, 35)`` row as a 2x128 tile per vertex, and the scatter-add
+    then costs several times more per edge than on a row-major
+    ``(n, 128)`` operand.  Zero columns add nothing, and each real column
+    sums its edges in the same order.
     """
 
     name = "edges"
@@ -336,20 +345,29 @@ class EdgesBackend(LocalBackend):
         self._dst = jnp.asarray(np.pad(g.dst, (0, pad), constant_values=g.n))
 
     def spmm(self, m):
-        accum = self.engine.policy.accum_dtype
-        n = self.engine.graph.n
+        eng = self.engine
+        accum = eng.policy.accum_dtype
+        n, b, c = m.shape
+        cols = b * c
+        flat = m.reshape(n, cols)
+        pad = -cols % eng.cost.lanes
+        if pad:
+            eng.counters["lane_padded_aggregations"] += 1
+            eng.counters["lane_pad_columns"] += pad
+            flat = jnp.pad(flat, ((0, 0), (0, pad)))
         chunk = self._edge_chunk
 
         def body(i, acc):
             src = jax.lax.dynamic_slice_in_dim(self._src, i * chunk, chunk)
             dst = jax.lax.dynamic_slice_in_dim(self._dst, i * chunk, chunk)
             return acc + jax.ops.segment_sum(
-                m[src].astype(accum), dst, num_segments=n, indices_are_sorted=True
+                flat[src].astype(accum), dst, num_segments=n, indices_are_sorted=True
             )
 
-        return jax.lax.fori_loop(
-            0, self._src.shape[0] // chunk, body, jnp.zeros(m.shape, accum)
+        total = jax.lax.fori_loop(
+            0, self._src.shape[0] // chunk, body, jnp.zeros(flat.shape, accum)
         )
+        return total[:, :cols].reshape(n, b, c)
 
 
 class EllBackend(LocalBackend):

@@ -5,10 +5,12 @@ described, not attached.  It refuses what interpret mode accepts: blocks not
 aligned to the (8, 128) tiling, scalar prefetch that overflows SMEM, and
 scratch that overflows VMEM.  These tests compile both kernels, and one
 fused-kernel engine stage, at the u7 and u12 widths the engine launches,
-and both kernels at the most operand rows the TPU pick admits.
+both kernels at the most operand rows the TPU pick admits, and the
+``edges`` backend's scatter-add on its lane-dense operand.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -183,3 +185,28 @@ def test_kernels_compile_at_row_bound(one_chip, kernel):
 
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("b,c", [(2, 35), (1, 28)])
+def test_edges_scatter_is_lane_dense(one_chip, monkeypatch, b, c):
+    """The ``edges`` backend's scatter-add at 2**19 vertices, with a TPU's
+    lane width: u7's ``(2, 35)`` rows (a ``(2, 128)`` tile per vertex as
+    they come) and u12's 28-column tail (laid out vertex-minor as it comes)
+    reach the compiler as one row-major ``(n, 128)`` operand."""
+    g = rmat_graph(1 << 19, 1 << 20, seed=1)
+    eng = CountingEngine(g, [get_template("u7")], backend="edges")
+    impl = eng.backend_impl
+    monkeypatch.setattr(eng.cost, "lanes", 128)
+    operands = jax.tree.map(
+        lambda a: _spec(one_chip, a.shape, a.dtype), impl.device_operands()
+    )
+
+    def run(operands, m):
+        with impl.bind_operands(operands):
+            return impl.spmm(m)
+
+    hlo = jax.jit(run).lower(operands, _spec(one_chip, (g.n, b, c), jnp.float32))
+    scatters = re.findall(
+        r"= f32\[([\d,]+)\]\{([\d,]+)[:}].*? scatter\(", hlo.compile().as_text()
+    )
+    assert scatters == [(f"{g.n},128", "1,0")]
