@@ -422,6 +422,7 @@ class CountingEngine:
             self.memory_budget_bytes,
             self.backend_impl.max_chunk_size(),
         )
+        self.backend_impl.publish_gauges(self.chunk_size)
 
         self._graph_signature: Optional[str] = None  # computed lazily
         if logger.isEnabledFor(logging.INFO):

@@ -362,3 +362,7 @@ class EngineBackend:
     def max_chunk_size(self) -> int:
         """Most colorings one launch may fuse, whatever the memory budget."""
         return MAX_CHUNK_SIZE
+
+    def publish_gauges(self, chunk: int) -> None:
+        """Set this backend's :func:`repro.tracing.gauge` values for a
+        launch of ``chunk`` colorings (host-side; none by default)."""

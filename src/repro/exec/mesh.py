@@ -138,13 +138,26 @@ class MeshBackend(EngineBackend):
         self.ema_mode = ema_mode
         self.gather_dtype = gather_dtype
         n_shards = int(np.prod(mesh.devices.shape))
-        # always the src-bucketed layout: blocking and pipelined engines
-        # then run over literally the same edge arrays (the precondition
-        # for their bit-exact A/B) and either mode can bind per stage
-        self.sharded = shard_graph(
-            engine.graph, n_shards, balance_degrees=balance_degrees,
-            bucket_by_src=True,
-        )
+        with tracing.span("shard_graph"):
+            # always the src-bucketed layout: blocking and pipelined engines
+            # then run over literally the same edge arrays (the precondition
+            # for their bit-exact A/B) and either mode can bind per stage
+            self.sharded = shard_graph(
+                engine.graph, n_shards, balance_degrees=balance_degrees,
+                bucket_by_src=True,
+            )
+            # each device holds its own edge partition; the relabel is replicated
+            by_shard = NamedSharding(mesh, P(tuple(mesh.axis_names)))
+            self._src = jax.device_put(self.sharded.src, by_shard)
+            self._dst_local = jax.device_put(self.sharded.dst_local, by_shard)
+            self._edge_mask = jax.device_put(self.sharded.edge_mask, by_shard)
+            # colorings follow the degree-balancing relabel (scatter old -> new;
+            # new ids range over [0, n_padded) with pad slots interleaved)
+            self._perm = (
+                jax.device_put(self.sharded.perm, NamedSharding(mesh, P()))
+                if self.sharded.perm is not None
+                else None
+            )
         if column_batch is None:
             column_batch = engine.cost.pick_mesh_column_batch()
         self.column_batch = int(column_batch)
@@ -212,18 +225,31 @@ class MeshBackend(EngineBackend):
             comm_schedule=stage_modes,
             bucket_stride=self.sharded.bucket_stride,
         )
-        # each device holds its own edge partition; the relabel is replicated
-        by_shard = NamedSharding(mesh, P(tuple(mesh.axis_names)))
-        self._src = jax.device_put(self.sharded.src, by_shard)
-        self._dst_local = jax.device_put(self.sharded.dst_local, by_shard)
-        self._edge_mask = jax.device_put(self.sharded.edge_mask, by_shard)
-        # colorings follow the degree-balancing relabel (scatter old -> new;
-        # new ids range over [0, n_padded) with pad slots interleaved)
-        self._perm = (
-            jax.device_put(self.sharded.perm, NamedSharding(mesh, P()))
-            if self.sharded.perm is not None
-            else None
-        )
+
+    def collective_bytes(self, chunk: int) -> int:
+        """Bytes one chip receives over the interconnect in one launch of
+        ``chunk`` colorings: ``n_shards - 1`` other shards' rows of every
+        stage's passive columns, padded to whole column batches, at the wire
+        dtype.  Blocking all-gathers each column batch once and the ring
+        passes it in ``n_shards - 1`` hops, so both move the same bytes."""
+        cb = self.column_batch
+        moved = {}  # the eMA modes other than streamed aggregate each passive state once
+        for s in self.engine.plan_ir.stages:
+            if not s.is_leaf:
+                key = s.position if self.ema_mode == "streamed" else s.passive_canon
+                moved[key] = -(-s.passive_columns // cb) * cb
+        wire = jnp.dtype(self.gather_dtype or self.engine.policy.store_dtype).itemsize
+        sg = self.sharded
+        return (sg.n_shards - 1) * sg.rows_per_shard * chunk * sum(moved.values()) * wire
+
+    def publish_gauges(self, chunk: int) -> None:
+        """Set the mesh gauges of :mod:`repro.tracing` for a launch of
+        ``chunk`` colorings."""
+        sg = self.sharded
+        tracing.gauge("mesh_collective_bytes", self.collective_bytes(chunk))
+        tracing.gauge("mesh_chunk", chunk)
+        tracing.gauge("mesh_edge_slots", sg.n_shards * sg.edges_per_shard)
+        tracing.gauge("mesh_edges", self.engine.graph.num_directed)
 
     def _pipeline_eligibility(self, n_shards: int):
         """Whether this geometry can run the ring at all — ``(ok, why)``."""

@@ -1,4 +1,4 @@
-"""Names on the chip's time: device scopes, host spans and a compile counter.
+"""Names on the chip's time: device scopes, host spans, a compile counter and gauges.
 
 This module is the only place that knows the names.
 
@@ -14,6 +14,12 @@ This module is the only place that knows the names.
   Each event is also filed under every :func:`span` open on the thread that
   compiled, so ``compile_counters("count_keys_chunk")`` is the compile work
   launches paid.
+* :func:`gauge` / :func:`gauges` — process-wide numbers a backend sets
+  when it binds, for readers that see no engine object.  The mesh backend
+  sets ``mesh_collective_bytes`` (bytes one chip receives over the
+  interconnect in one launch), ``mesh_chunk`` (that launch's colourings),
+  ``mesh_edge_slots`` and ``mesh_edges`` (padded and real directed edges
+  over all shards); the most recently bound mesh engine's values stand.
 
 Scopes of a launch (``CountingEngine.launch_scopes``)::
 
@@ -30,6 +36,9 @@ Spans of a launch (``CountingEngine.count_keys_chunk``)::
       s2v.dispatch        key upload, padding and the program call
       s2v.fetch           wait for the device and copy the result to the host
       s2v.scale           float64 normalisation and the fault seam's result hook
+
+Span of a mesh engine's construction: ``s2v.shard_graph``, the row
+partition of the graph and the placement of its edge operands on the chips.
 """
 
 from __future__ import annotations
@@ -49,6 +58,8 @@ __all__ = [
     "stage",
     "span",
     "compile_counters",
+    "gauge",
+    "gauges",
     "op_scopes",
 ]
 
@@ -73,6 +84,7 @@ _local = threading.local()
 _lock = threading.Lock()
 # (span name or None for every event, short event name) -> [count, seconds]
 _compiles: Dict[Tuple[Optional[str], str], List[float]] = defaultdict(lambda: [0, 0.0])
+_gauges: Dict[str, float] = {}
 
 
 def _open(kind: str) -> List[str]:
@@ -153,6 +165,18 @@ def compile_counters(span_name: Optional[str] = None) -> Dict[str, Dict[str, flo
     with _lock:
         got = {short: _compiles.get((span_name, short), (0, 0.0)) for short in COMPILE_EVENTS.values()}
     return {short: {"count": int(n), "seconds": float(sec)} for short, (n, sec) in got.items()}
+
+
+def gauge(name: str, value: float) -> None:
+    """Set the process-wide gauge ``name`` (host-side; no device work)."""
+    with _lock:
+        _gauges[name] = value
+
+
+def gauges() -> Dict[str, float]:
+    """Snapshot of every :func:`gauge` set since import."""
+    with _lock:
+        return dict(_gauges)
 
 
 _INSTR = re.compile(r"^\s*(?:ROOT\s+)?%?([^\s=]+)\s*=")
