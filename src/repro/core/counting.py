@@ -439,6 +439,7 @@ def liveness_peak_columns(
     canons,
     pad_unit: int = 1,
     track_products: bool = False,
+    footprint: Optional[Callable[[int], int]] = None,
 ) -> int:
     """Peak live M columns per coloring under the liveness-aware schedule.
 
@@ -447,8 +448,12 @@ def liveness_peak_columns(
     to ``pad_unit``), plus — when ``track_products`` — the memoized
     aggregate product of the stage's passive state.  ``track_products=False``
     models the fused pipeline, where no aggregate product ever exists.
+    ``footprint`` maps a state's columns to what it holds instead of the
+    padded columns (a device layout's figure); the peak is then of those.
     """
     def pad_cols(c: int) -> int:
+        if footprint is not None:
+            return footprint(c)
         return ((c + pad_unit - 1) // pad_unit) * pad_unit
 
     k = plans[0].k

@@ -8,7 +8,7 @@ exactly::
     cost   = repro.plan.cost.CostModel(plan, graph, ...) # calibrated budgets
     select_backend(graph)                                # graph statistics
     repro.exec.make_backend(engine)                      # bind plan to devices
-    chunk  = cost.pick_chunk_size(...)                   # fit the budget
+    chunk  = backend.pick_chunk_size(budget)             # fit the budget
 
 and every public surface — :meth:`CountingEngine.describe`,
 :meth:`CountingEngine.cache_key`, the memory figures, the chunked launch
@@ -417,10 +417,10 @@ class CountingEngine:
         # budget", which is itself deterministic given the budget
         self._chunk_explicit = bool(chunk_size)
         self._column_batch_arg = column_batch
-        self.chunk_size = int(chunk_size) if chunk_size else self.cost.pick_chunk_size(
-            self.bytes_per_coloring(),
-            self.memory_budget_bytes,
-            self.backend_impl.max_chunk_size(),
+        self.chunk_size = (
+            int(chunk_size)
+            if chunk_size
+            else self.backend_impl.pick_chunk_size(self.memory_budget_bytes)
         )
         self.backend_impl.publish_gauges(self.chunk_size)
 
@@ -588,7 +588,7 @@ class CountingEngine:
 
     def predicted_peak_bytes(self) -> int:
         """The chunk picker's live-footprint prediction for one chunk."""
-        return self.chunk_size * self.bytes_per_coloring()
+        return self.backend_impl.chunk_bytes(self.chunk_size)
 
     def compiled_memory_analysis(self, iterations: Optional[int] = None) -> Dict[str, Optional[float]]:
         """Compile one run and compare XLA's measured temp allocation with

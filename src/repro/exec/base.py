@@ -359,6 +359,18 @@ class EngineBackend:
             self.transient_elements(), self.resident_elements()
         )
 
+    def chunk_bytes(self, chunk: int) -> int:
+        """Predicted live bytes of one launch of ``chunk`` colorings."""
+        return chunk * self.bytes_per_coloring()
+
+    def pick_chunk_size(self, memory_budget_bytes: int) -> int:
+        """Colorings one launch fuses under ``memory_budget_bytes``: the
+        largest chunk whose :meth:`chunk_bytes` fit, capped by
+        :meth:`max_chunk_size`."""
+        return self.engine.cost.pick_chunk_size(
+            self.bytes_per_coloring(), memory_budget_bytes, self.max_chunk_size()
+        )
+
     def max_chunk_size(self) -> int:
         """Most colorings one launch may fuse, whatever the memory budget."""
         return MAX_CHUNK_SIZE

@@ -21,7 +21,7 @@ import jax.numpy as jnp
 from repro import tracing
 from repro.core.counting import fused_aggregate_ema_grouped
 from repro.core.graph import BLOCKED_BLOCK_SIZE, build_sell
-from repro.plan.cost import EDGE_CHUNK
+from repro.plan.cost import EDGE_CHUNK, pick_chunk_by_lanes
 
 from .base import (
     BagStageTables,
@@ -368,6 +368,38 @@ class EdgesBackend(LocalBackend):
             0, self._src.shape[0] // chunk, body, jnp.zeros(flat.shape, accum)
         )
         return total[:, :cols].reshape(n, b, c)
+
+    # -- the chunk, priced as the lane-dense program lays it out -------------
+
+    def chunk_bytes(self, chunk: int) -> int:
+        """One figure for the whole chunk where lanes pad
+        (:meth:`~repro.plan.cost.CostModel.edges_chunk_bytes`): the flattened
+        aggregation pads ``B * w`` once a slice, not once a coloring.  With
+        one lane, or bag stages, the per-coloring figure times ``chunk``."""
+        eng = self.engine
+        if eng.cost.lanes == 1 or eng.plan_ir.has_bag_stages:
+            return super().chunk_bytes(chunk)
+        return eng.cost.edges_chunk_bytes(chunk, eng.column_batch)
+
+    def _lane_slots(self, chunk: int) -> int:
+        return self.engine.cost.edges_lane_slots(chunk, self.engine.column_batch)
+
+    def pick_chunk_size(self, memory_budget_bytes: int) -> int:
+        """The chunk that fills the aggregation's lane tiles best among
+        those that fit (:func:`~repro.plan.cost.pick_chunk_by_lanes`): the
+        fewest padded lanes a coloring, the larger chunk on a tie.  With one
+        lane every chunk ties, and the largest that fits wins."""
+        return pick_chunk_by_lanes(
+            self.chunk_bytes, self._lane_slots, memory_budget_bytes, self.max_chunk_size()
+        )
+
+    def publish_gauges(self, chunk: int) -> None:
+        """``edges_lane_slots``: padded lanes one launch of ``chunk``
+        colorings aggregates, over its sweeps; ``edges_lane_used``: the
+        ``B * w`` of them that carry columns."""
+        used = chunk * sum(self.engine.cost.edges_sweep_widths(self.engine.column_batch))
+        tracing.gauge("edges_lane_slots", self._lane_slots(chunk))
+        tracing.gauge("edges_lane_used", used)
 
 
 class EllBackend(LocalBackend):

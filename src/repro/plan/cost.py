@@ -10,6 +10,10 @@ used to scatter across backends and the chunk picker:
   slice of the backend's gather scratch (edge messages, padded rows, SELL
   groups, the all-gather buffer);
 * **column-batch picking** — the fused-slice width per target;
+* the **``edges`` chunk on a TPU** — one figure for a whole chunk as the
+  lane-dense program lays it out, and among the chunks that fit, the one
+  that fills the aggregation's 128-lane rows best
+  (:meth:`CostModel.edges_chunk_elements`, :func:`pick_chunk_by_lanes`);
 * **chunk picking** — the largest coloring chunk whose live footprint fits
   the memory budget, with the analytic byte model corrected by the
 
@@ -30,7 +34,8 @@ import logging
 import math
 import os
 from dataclasses import dataclass
-from typing import Dict, Optional
+from fractions import Fraction
+from typing import Callable, Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
@@ -48,9 +53,11 @@ __all__ = [
     "fusion_slack_factor",
     "mesh_link_bytes_per_us",
     "pick_chunk_size",
+    "pick_chunk_by_lanes",
     "DEFAULT_MEMORY_BUDGET_BYTES",
     "DEVICE_BUDGET_FRACTION",
     "TPU_LANES",
+    "TPU_SUBLANES",
     "default_memory_budget_bytes",
     "MAX_CHUNK_SIZE",
     "EDGE_CHUNK",
@@ -74,10 +81,16 @@ DEFAULT_MEMORY_BUDGET_BYTES = 32 * 1024 * 1024
 #: rest holds the graph operands, XLA temporaries and allocator slack.
 DEVICE_BUDGET_FRACTION = 0.5
 
-#: Minor-dimension tile width of TPU arrays: the DP state's column axis is
-#: the minor one of its ``(n, B, C)`` layout, so on a TPU every state and
-#: gathered slice occupies whole 128-lane tiles.
+#: Minor-dimension tile width of TPU arrays.  The shared formulas take the
+#: DP state's column axis as the minor one of its ``(n, B, C)`` layout, so
+#: every state and gathered slice occupies whole 128-lane tiles: an upper
+#: bound, since XLA lays the states out vertex-minor
+#: (:meth:`CostModel.edges_chunk_elements` prices that layout).
 TPU_LANES = 128
+
+#: Rows of a 32-bit TPU array tile: the axis next to the minor one pads to
+#: a multiple of this (``T(8,128)``); a 16-bit dtype packs two rows in one.
+TPU_SUBLANES = 8
 
 #: Hard cap on colorings fused into one chunk (diminishing returns beyond).
 MAX_CHUNK_SIZE = 64
@@ -316,6 +329,26 @@ def pick_chunk_size(
     return max(1, min(max_chunk, int(memory_budget_bytes // bytes_per_coloring)))
 
 
+def pick_chunk_by_lanes(
+    chunk_bytes: Callable[[int], int],
+    lane_slots: Callable[[int], int],
+    memory_budget_bytes: int,
+    max_chunk: int = MAX_CHUNK_SIZE,
+) -> int:
+    """Among the chunks ``1..max_chunk`` whose ``chunk_bytes`` fit the
+    budget, the one with the fewest ``lane_slots`` a coloring; on a tie the
+    larger chunk; 1 where none fits.
+
+    Where every chunk pads nothing (one lane), the slots a coloring are the
+    same for every chunk and the rule is :func:`pick_chunk_size`'s "largest
+    that fits".
+    """
+    fits = [b for b in range(1, max_chunk + 1) if chunk_bytes(b) <= memory_budget_bytes]
+    if not fits:
+        return 1
+    return min(fits, key=lambda b: (Fraction(lane_slots(b), b), -b))
+
+
 @dataclass(frozen=True)
 class AdmissionEstimate:
     """Predicted footprint of one query, for serving-layer load shedding.
@@ -551,6 +584,75 @@ class CostModel:
 
     def _lane_padded(self, columns: int) -> int:
         return -(-columns // self.lanes) * self.lanes
+
+    # -- the edges backend's chunk (lane-dense aggregation) ------------------
+
+    def edges_sweep_widths(self, column_batch: int) -> List[int]:
+        """Columns of each aggregation one coloring's launch runs on the
+        ``edges`` backend: every passive state's columns in
+        ``column_batch``-wide slices.  A state that stages of separate exec
+        groups read is aggregated by each of them with the same slices, and
+        XLA merges the identical computations into one pass (u7's leaf: four
+        traced aggregations, three scatter fusions on the chip), so each
+        passive state counts once."""
+        passive = {
+            s.passive_canon: s.passive_columns
+            for s in self.plan.stages
+            if s.passive_columns and not s.is_bag
+        }
+        cb = max(1, int(column_batch))
+        return [min(cb, c - lo) for c in passive.values() for lo in range(0, c, cb)]
+
+    def edges_lane_slots(self, chunk: int, column_batch: int) -> int:
+        """Padded lanes one launch of ``chunk`` colorings aggregates, summed
+        over its sweeps: ``EdgesBackend.spmm`` flattens a ``(n, B, w)``
+        slice to ``B * w`` columns and pads them to whole lane tiles."""
+        return sum(
+            self._lane_padded(chunk * w) for w in self.edges_sweep_widths(column_batch)
+        )
+
+    def edges_chunk_elements(self, chunk: int, column_batch: int) -> int:
+        """Live elements of one launch of ``chunk`` colorings on the
+        ``edges`` backend, as the TPU program lays them out.
+
+        * The DP states at the liveness peak.  XLA lays an ``(n, B, C)``
+          state out vertex-minor: ``n`` pads to whole lanes, and the axis
+          next to it to whole tile rows (:data:`TPU_SUBLANES` at 32 bits).
+          That axis is ``C`` (layout ``{0,2,1}``) or ``B`` (``{0,1,2}``);
+          XLA picks either and has held one state in both at once, so each
+          state counts the worse, ``max(B * pad(C), pad(B) * C)``.  A
+          size-1 ``B`` stays out of the tile (an ``f32[524288,1,924]``
+          state reads ``{0,2,1:T(8,128)}``).
+        * The aggregation of the widest sweep: its flattened operand and
+          its accumulator, each ``n * pad_lanes(B * w)``.
+
+        The whole chunk is priced at once: the states are not lane-padded
+        per coloring, and the aggregation pads ``B * w`` once a slice.  On a
+        v5e (7.87 GiB budget) this admits u7's chunk 10 at scale 20 and
+        refuses 11–18; chunks 10 and 14 compile there, 18 does not.
+        """
+        b = int(chunk)
+        rows = TPU_SUBLANES * 4 // self.itemsize
+
+        def pad_rows(x: int) -> int:
+            return -(-x // rows) * rows
+
+        def state(columns: int) -> int:
+            tiled = b * pad_rows(columns)
+            return max(tiled, pad_rows(b) * columns) if b > 1 else tiled
+
+        n = self.graph.n
+        widest = max(self.edges_sweep_widths(column_batch), default=0)
+        return (
+            self._lane_padded(n) * self.plan.peak_footprint(state)
+            + 2 * n * self._lane_padded(b * widest)
+        )
+
+    def edges_chunk_bytes(self, chunk: int, column_batch: int) -> int:
+        """:meth:`edges_chunk_elements` in bytes.  The fusion slack is not
+        applied: it calibrates the per-coloring model against XLA:CPU's
+        temporaries, and a TPU's temp figure fills whatever memory is free."""
+        return self.edges_chunk_elements(chunk, column_batch) * self.itemsize
 
     def transient_elements(
         self,

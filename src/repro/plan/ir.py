@@ -24,7 +24,7 @@ the canonical states that are dead after position ``pos``.
 from __future__ import annotations
 
 from dataclasses import dataclass, field
-from typing import Dict, List, Mapping, Optional, Sequence, Tuple, Union
+from typing import Callable, Dict, List, Mapping, Optional, Sequence, Tuple, Union
 
 from repro.core.colorsets import binom
 from repro.core.counting import (
@@ -222,6 +222,13 @@ class TemplatePlan:
             self.canons,
             pad_unit=pad_unit,
             track_products=track_products,
+        )
+
+    def peak_footprint(self, footprint: Callable[[int], int]) -> int:
+        """Liveness peak of ``footprint(columns)`` summed over the live
+        states (the fused pipeline's schedule; a device layout's figure)."""
+        return liveness_peak_columns(
+            self.counting_plans, self.canons, footprint=footprint
         )
 
     def peak_elements(self, n: int) -> int:

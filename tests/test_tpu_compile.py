@@ -6,11 +6,13 @@ aligned to the (8, 128) tiling, scalar prefetch that overflows SMEM, and
 scratch that overflows VMEM.  These tests compile both kernels, and one
 fused-kernel engine stage, at the u7 and u12 widths the engine launches,
 both kernels at the most operand rows the TPU pick admits, and the
-``edges`` backend's scatter-add on its lane-dense operand.
+``edges`` backend's scatter-add on its lane-dense operand, alone and in a
+whole u7 launch at the chunk the TPU pick takes.
 """
 
 import os
 import re
+from types import SimpleNamespace
 
 import jax
 import jax.numpy as jnp
@@ -21,6 +23,7 @@ from repro.core import CountingEngine, build_counting_plan, get_template, rmat_g
 from repro.core.colorsets import binom
 from repro.kernels.spmm_blocked.kernel import spmm_blocked_call
 from repro.exec.select import BLOCKED_MAX_ROWS
+from repro.plan.cost import DEVICE_BUDGET_FRACTION, CostModel, pick_chunk_by_lanes
 from repro.kernels.spmm_ema.kernel import VMEM_BUDGET_BYTES, pad8, spmm_ema_call, vmem_bytes
 
 #: Colorings per launch: a chunk the engine's VMEM cap admits at u12.
@@ -28,6 +31,8 @@ CHUNK = 4
 BLOCK = 256
 ROWS = 2048  # operand rows (fixed-capacity slices of block pairs)
 N_PADDED = 8192
+#: ``memory_stats()["bytes_limit"]`` of one TPU v5e chip (15.75 GiB).
+V5E_BYTES_LIMIT = 16_909_336_064
 
 
 @pytest.fixture(scope="module")
@@ -210,3 +215,34 @@ def test_edges_scatter_is_lane_dense(one_chip, monkeypatch, b, c):
         r"= f32\[([\d,]+)\]\{([\d,]+)[:}].*? scatter\(", hlo.compile().as_text()
     )
     assert scatters == [(f"{g.n},128", "1,0")]
+
+
+def test_edges_launch_fills_lanes_at_the_picked_chunk(one_chip, monkeypatch):
+    """u7's whole ``edges`` launch, at the chunk the TPU pick takes for the
+    scale-20 cell (v5e budget), on a 2**16-vertex R-MAT graph: every
+    scatter-add's operand is ``(n, 128 j)`` with ``j = ceil(B w / 128)``
+    for u7's 7- and 35-column sweeps, and the program compiles."""
+    g = rmat_graph(1 << 16, 1 << 19, seed=1)
+    eng = CountingEngine(g, [get_template("u7")], backend="edges", column_batch=35)
+    scale20 = CostModel(eng.plan_ir, SimpleNamespace(n=1 << 20, num_directed=31_404_412))
+    scale20.lanes = 128
+    b = pick_chunk_by_lanes(
+        lambda c: scale20.edges_chunk_bytes(c, 35),
+        lambda c: scale20.edges_lane_slots(c, 35),
+        int(V5E_BYTES_LIMIT * DEVICE_BUDGET_FRACTION),
+    )
+    assert b == 10
+    impl = eng.backend_impl
+    monkeypatch.setattr(eng.cost, "lanes", 128)
+    operands = jax.tree.map(
+        lambda a: _spec(one_chip, a.shape, a.dtype), impl.device_operands()
+    )
+
+    def run(operands, keys):
+        with impl.bind_operands(operands):
+            return impl.counts_for_keys_chunk(keys)
+
+    compiled = jax.jit(run).lower(operands, _spec(one_chip, (b, 2), jnp.uint32)).compile()
+    scatters = re.findall(r"= f32\[([\d,]+)\]\{[\d,]+[:}].*? scatter\(", compiled.as_text())
+    want = {f"{g.n},{-(-b * w // 128) * 128}" for w in (7, 35)}
+    assert scatters and set(scatters) == want
