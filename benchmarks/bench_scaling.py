@@ -7,17 +7,9 @@ sizes 1/2/4/8:
 
 * per-shard M-matrix bytes (the paper's Fig 12 memory-extension claim),
 * per-shard HLO flops (compute splits linearly),
-* all-gather wire bytes (the communication the column batching bounds).
-
-The ``fig13/ring`` row family is the wall-clock half: an interleaved A/B
-of ``mesh_comm="blocking"`` vs ``"pipelined"`` (same process, same graph,
-alternating arms) through the ``scripts/perf_subgraph_u20.py`` driver, at a
-working-set size where the all-gathered column buffer falls out of cache
-but the ring's two circulating slices do not.  Each row records the
-pipelined us/coloring plus ``ratio=`` (pipelined/blocking, < 1.0 is a ring
-win), ``per_shard_byte_frac`` (transient footprint of the ring arm as a
-fraction of blocking's), and ``overlap_eff`` (measured fraction of the
-modeled wire time hidden).
+* collective wire bytes per chip, as the mesh backend itself counts them
+  (``MeshBackend.collective_bytes``: the communication the column batching
+  bounds).
 
 On CPU the mesh sizes run in a child process with eight virtual devices
 (``XLA_FLAGS`` is read when the child's backend starts).  On a chip host they
@@ -31,7 +23,6 @@ import json
 import os
 import subprocess
 import sys
-import tempfile
 
 from .common import record
 
@@ -46,7 +37,6 @@ def _strong_scaling(device_counts):
     import numpy as np
 
     from repro.core import CountingEngine, get_template, rmat_graph
-    from repro.launch.roofline import collective_wire_bytes
 
     g = rmat_graph(16384, 160_000, seed=7)
     t = get_template("u7")
@@ -56,7 +46,7 @@ def _strong_scaling(device_counts):
         mesh = jax.make_mesh((n_dev,), ("data",), devices=jax.devices()[:n_dev])
         # the engine's mesh backend: one-coloring chunk for the per-shard probe
         eng = CountingEngine(g, [t], backend="mesh", mesh=mesh, column_batch=8,
-                             ema_mode="loop", chunk_size=1)
+                             chunk_size=1)
         run = eng.backend_impl.jit(eng.backend_impl.counts_for_colors)
         compiled = run.lower(colors).compile()
         val = float(run(colors)[0, 0])
@@ -64,13 +54,12 @@ def _strong_scaling(device_counts):
         jax.block_until_ready(run(colors))
         dt = time.perf_counter() - t0
         ca = compiled.cost_analysis() or {}
-        coll, _ = collective_wire_bytes(compiled.as_text())
         out.append({
             "devices": n_dev,
             "wall_s": dt,
             "flops_per_shard": ca.get("flops", 0.0),
             "bytes_per_shard": ca.get("bytes accessed", 0.0),
-            "collective_bytes": coll,
+            "collective_bytes": eng.backend_impl.collective_bytes(1),
             "count": val,
         })
     return out
@@ -120,59 +109,4 @@ def run() -> None:
             f"flops_per_shard_frac={d['flops_per_shard'] / max(base['flops_per_shard'], 1):.3f};"
             f"bytes_per_shard_frac={d['bytes_per_shard'] / max(base['bytes_per_shard'], 1):.3f}",
         )
-    _run_ring()
 
-
-def _ring_args(n_dev: int, out: str) -> list:
-    return [
-        "--devices", str(n_dev), "--template", "u7",
-        "--n", "65536", "--edges", "262144",
-        "--column-batch", "256", "--chunk-size", "2",
-        "--iters", "2", "--repeats", "2", "--out", out,
-    ]
-
-
-def _run_ring() -> None:
-    """fig13/ring rows: interleaved blocking-vs-pipelined A/B per mesh size.
-
-    Runs the perf driver (it owns the interleaving discipline); the config
-    is sized so the all-gathered buffer (n_padded x B x cb ~ 256 MB) spills
-    cache while a ring slice does not.  On CPU each mesh size runs in a
-    child with virtual devices; on a chip host it runs here, over real
-    devices.
-    """
-    import jax
-
-    on_cpu = jax.default_backend() == "cpu"
-    sizes = (4, 8) if on_cpu else tuple(d for d in (4, 8) if d <= len(jax.devices()))
-    for n_dev in sizes:
-        out = os.path.join(tempfile.mkdtemp(prefix="fig13_ring_"), "ab.json")
-        if on_cpu:
-            subprocess.run(
-                [sys.executable, "scripts/perf_subgraph_u20.py", *_ring_args(n_dev, out)],
-                check=True, capture_output=True, text=True, env=_cpu_env(), timeout=1800,
-            )
-        else:
-            _perf_driver().main(_ring_args(n_dev, out))
-        with open(out) as fh:
-            ab = json.load(fh)
-        assert ab["bit_exact"], f"A/B arms diverged at {n_dev} devices"
-        record(
-            f"fig13/ring/{n_dev}dev",
-            ab["pipelined"]["us_per_coloring"],
-            f"ratio={ab['ratio_pipelined_vs_blocking']:.3f};"
-            f"per_shard_byte_frac={ab['per_shard_byte_fraction']:.3f};"
-            f"overlap_eff={ab['measured_overlap_efficiency']:.2f}",
-        )
-
-
-def _perf_driver():
-    """``scripts/perf_subgraph_u20.py`` as a module (scripts/ is no package)."""
-    import importlib.util
-
-    spec = importlib.util.spec_from_file_location(
-        "perf_subgraph_u20", os.path.join("scripts", "perf_subgraph_u20.py")
-    )
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod

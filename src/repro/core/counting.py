@@ -237,30 +237,6 @@ def _ema_apply(
     return jax.lax.fori_loop(0, n_splits, body, init)
 
 
-def _ema_apply_fused(
-    m_a: jnp.ndarray,
-    b: jnp.ndarray,
-    idx_a: jnp.ndarray,
-    idx_p: jnp.ndarray,
-    init: jnp.ndarray,
-) -> jnp.ndarray:
-    """:func:`_ema_apply` on the engine's fused ``(n, B, C)`` layout.
-
-    Column gathers run on axis 2; ``init`` fixes the accumulator shape and
-    dtype (and, for shard_map callers, its varying axes).  Shared by the
-    local engine backends and the mesh DP so the two cannot drift.
-    """
-    n_splits = idx_a.shape[1]
-    accum = init.dtype
-
-    def body(t, acc):
-        ga = jnp.take(m_a, idx_a[:, t], axis=2).astype(accum)
-        gp = jnp.take(b, idx_p[:, t], axis=2).astype(accum)
-        return acc + ga * gp
-
-    return jax.lax.fori_loop(0, n_splits, body, init)
-
-
 def _fused_batch_apply(
     m_s: jnp.ndarray,
     m_a: jnp.ndarray,
@@ -386,16 +362,15 @@ def fused_aggregate_ema_grouped(
     return outs
 
 
-def schedule_liveness(plans, canons, track_products: bool = False):
-    """Last-read position for every shared DP state (and SpMM product).
+def schedule_liveness(plans, canons):
+    """Last-read position for every shared DP state.
 
     The multi-template schedule executes each canonical sub-template once
     (first occurrence across plans) and reads each plan's root at the end of
-    that plan.  Returns ``free_at``: position -> list of keys (canonical
-    strings, or ``("prod", canon)`` for memoized aggregate products when
-    ``track_products``) that are dead after that position, so executors can
-    drop them and peak memory matches Algorithm 5's in-place storage instead
-    of growing with the number of stages.
+    that plan.  Returns ``free_at``: position -> list of canonical strings
+    that are dead after that position, so executors can drop them and peak
+    memory matches Algorithm 5's in-place storage instead of growing with
+    the number of stages.
     """
     executed = set()
     last_read = {}
@@ -410,15 +385,11 @@ def schedule_liveness(plans, canons, track_products: bool = False):
                 if not sub.is_leaf:
                     last_read[pc[sub.active]] = pos
                     last_read[pc[sub.passive]] = pos
-                    if track_products:
-                        last_read[("prod", pc[sub.passive])] = pos
                 pos += 1
             last_read[pc[plan.partition.root_index]] = pos
             pos += 1
         else:
-            # Bag plans: same first-occurrence / position discipline; bag ops
-            # have no memoized aggregate products (extend SpMMs consume their
-            # input directly), so track_products adds nothing here.
+            # Bag plans: same first-occurrence / position discipline.
             for i, op in enumerate(plan.bag_program.ops):
                 if pc[i] in executed:
                     continue
@@ -438,16 +409,14 @@ def liveness_peak_columns(
     plans,
     canons,
     pad_unit: int = 1,
-    track_products: bool = False,
     footprint: Optional[Callable[[int], int]] = None,
 ) -> int:
     """Peak live M columns per coloring under the liveness-aware schedule.
 
     Simulates the multi-template DP with eager freeing: per executed stage
     the live set holds every not-yet-dead canonical state (columns padded up
-    to ``pad_unit``), plus — when ``track_products`` — the memoized
-    aggregate product of the stage's passive state.  ``track_products=False``
-    models the fused pipeline, where no aggregate product ever exists.
+    to ``pad_unit``); the pipeline is fused, so no aggregate product ever
+    exists.
     ``footprint`` maps a state's columns to what it holds instead of the
     padded columns (a device layout's figure); the peak is then of those.
     """
@@ -457,7 +426,7 @@ def liveness_peak_columns(
         return ((c + pad_unit - 1) // pad_unit) * pad_unit
 
     k = plans[0].k
-    free_at = schedule_liveness(plans, canons, track_products=track_products)
+    free_at = schedule_liveness(plans, canons)
     executed = set()
     live = {}
     peak = 0
@@ -466,23 +435,13 @@ def liveness_peak_columns(
         pc = canons[p_idx]
         if plan.partition is not None:
             stage_widths = [binom(k, sub.size) for sub in plan.partition.subs]
-            stage_prod = [
-                (pc[sub.passive], binom(k, plan.partition.subs[sub.passive].size))
-                if (not sub.is_leaf and track_products)
-                else None
-                for sub in plan.partition.subs
-            ]
         else:
             stage_widths = [binom(k, op.m) for op in plan.bag_program.ops]
-            stage_prod = [None] * len(stage_widths)
         for i, width in enumerate(stage_widths):
             if pc[i] in executed:
                 continue
             executed.add(pc[i])
             live[pc[i]] = pad_cols(width)
-            if stage_prod[i] is not None:
-                prod_canon, prod_width = stage_prod[i]
-                live.setdefault(("prod", prod_canon), pad_cols(prod_width))
             peak = max(peak, sum(live.values()))
             for key in free_at.get(pos, ()):
                 live.pop(key, None)

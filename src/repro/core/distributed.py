@@ -10,11 +10,14 @@ stage:
 * **SpMM** — the only communicating step.  The dense count matrix
   ``M_{s,p}`` is broadcast in **column batches** (the paper's batched SpMM,
   §V-C: "we also split columns of M_{s,p} into batches ... to save peak
-  memory"): for each batch, ``all_gather`` the batch rows along the mesh,
-  then a local edge segment-sum produces the batch of ``B``.
+  memory"): for each batch, ``all_gather`` the batch rows along the mesh
+  (or pass them round a ``ppermute`` ring), then a local edge segment-sum
+  produces the batch of ``B``.
   Peak extra memory = one batch = ``n * batch_size * column_batch * 4`` bytes.
 * **eMA** — entirely vertex-local (Equation 1's whole point), zero
-  communication.
+  communication.  It is streamed: each batch of ``B`` is consumed by the
+  eMA updates that read it as soon as it is reduced, so ``B`` is never
+  whole.
 
 The final count is a ``psum`` of local totals.  Column batching makes the
 collective volume *independent* of the template size per batch; the batch
@@ -48,16 +51,13 @@ from jax.sharding import PartitionSpec as P
 from repro import tracing
 
 from .colorsets import binom
-from .counting import CountingPlan, _ema_apply_fused, schedule_liveness
+from .counting import CountingPlan
 from .graph import Graph
 
 __all__ = [
     "ShardedGraph",
     "shard_graph",
     "make_batched_count_fn",
-    "make_distributed_count_fn",
-    "distributed_input_specs",
-    "build_streamed_tables",
 ]
 
 
@@ -289,40 +289,17 @@ def _streamed_stage_tables(table, column_batch: int):
     )
 
 
-def build_streamed_tables(plan: CountingPlan, column_batch: int):
-    """Per-stage split tables re-bucketed by passive-column batch.
-
-    The streamed schedule (§Perf beyond-paper optimization) consumes each
-    all-gathered SpMM column batch immediately: for batch ``bi`` it applies
-    every (out, split) entry whose passive column falls in the batch.  ``B``
-    is never materialized — peak per-stage memory drops from
-    ``M_a + M_p + B + M_s`` to ``M_a + M_p + M_s + one batch`` and the
-    B write+read HBM round-trip disappears.
-
-    Returns ``{stage: (ent_out, ent_ia, ent_ip_local, ent_valid)}`` with
-    arrays shaped ``(n_batches, cap)`` (padded per batch).
-    """
-    return {
-        i: _streamed_stage_tables(t, column_batch)
-        for i, t in enumerate(plan.tables)
-        if t is not None
-    }
-
-
 def make_batched_count_fn(
     plans: Sequence[CountingPlan],
     mesh: Mesh,
     n_padded: int,
     edges_per_shard: int,
     *,
-    column_batch: Optional[int] = 128,
-    ema_mode: str = "streamed",
+    plan_ir,
+    column_batch: int = 128,
     gather_dtype=None,
-    canons: Optional[Sequence[Sequence[str]]] = None,
-    plan_ir=None,
     store_dtype=jnp.float32,
     accum_dtype=jnp.float32,
-    comm_mode: str = "blocking",
     comm_schedule: Optional[Mapping[Tuple[int, int], str]] = None,
     bucket_stride: Optional[int] = None,
 ) -> Callable:
@@ -334,57 +311,46 @@ def make_batched_count_fn(
       (colors (B, n_padded) i32, src (S*E,) i32, dst_local (S*E,) i32,
        edge_mask (S*E,) f32) -> (B, T) f32 raw colorful totals
 
-    where ``T == len(plans)``.  All split tables (plain or streamed) are
-    built HERE, once, de-duplicated by ``(k, m, m_a)``, and closure-captured
-    — they are never re-shipped per call.  A chunk of ``B`` colorings is
-    fused into the batch dimension of the DP state so every all-gathered
-    column batch serves all ``B`` colorings in one collective.
+    where ``T == len(plans)``.  Every stage is the streamed SpMM -> eMA:
+    each all-gathered column batch of the passive state is consumed at once
+    by the eMA updates that read it, so the aggregate product ``B`` never
+    exists.  The split tables, re-bucketed by passive-column batch, are
+    built HERE, once, de-duplicated by ``(k, m, m_a)``, and
+    closure-captured — they are never re-shipped per call.  A chunk of
+    ``B`` colorings is fused into the batch dimension of the DP state so
+    every all-gathered column batch serves all ``B`` colorings in one
+    collective.
 
     Args:
       plans: one or more same-``k`` :class:`CountingPlan`; DP states are
-        shared across plans by rooted canonical form (see ``canons``).
+        shared across plans by rooted canonical form.
       mesh: the device mesh; tensors are sharded over every axis (1-D row
         partition of the vertex space).
       n_padded / edges_per_shard: the :class:`ShardedGraph` geometry.
-      column_batch: passive columns all-gathered per collective.  ``None`` is
-        probe mode: one full-width all-gather, no loop — lets
-        ``cost_analysis`` see the full per-stage work (XLA counts while-loop
-        bodies once).
-      ema_mode: ``"streamed"`` (beyond-paper fusion: every all-gathered
-        column batch is consumed immediately by the eMA updates that read
-        it; ``B`` never exists), ``"loop"`` (paper-faithful Algorithm 5:
-        full batched SpMM into B, then the eMA pass; B is memoized per
-        passive canonical form, so templates sharing a passive sub-template
-        share its SpMM), or ``"vectorized"`` (probe mode: loop-free
-        gather-FMA einsum, fully visible to ``cost_analysis``).
+      plan_ir: the :class:`repro.plan.ir.TemplatePlan` of ``plans`` — its
+        schedule (canonical sharing, liveness, exec groups) is consumed,
+        not re-derived.
+      column_batch: passive columns all-gathered per collective.
       gather_dtype: ``jnp.bfloat16`` compresses the row all-gather payload 2x
         — the counting analogue of gradient compression.  Counts are an
         (eps, delta) ESTIMATOR, so the ~0.4% bf16 rounding is dominated by
         coloring variance.  Accumulation stays fp32.
-      canons: per-plan, per-sub-template rooted canonical strings (legacy
-        override; superseded by ``plan_ir``); equal strings share one DP
-        state.
-      plan_ir: optional :class:`repro.plan.ir.TemplatePlan` for the plan
-        set — the engine's mesh backend passes its bound plan so the
-        schedule (canonical sharing + liveness) is consumed, not
-        re-derived.  Legacy callers omit it and one is planned here.
       store_dtype / accum_dtype: the engine's dtype policy — M matrices are
         kept (and all-gathered) in ``store_dtype``, reductions accumulate in
         ``accum_dtype``.
-      comm_mode: ``"blocking"`` (one ``all_gather`` per column batch — the
-        paper's synchronous scheme) or ``"pipelined"`` (double-buffered ring:
-        each column batch circulates as per-shard row slices via
-        ``lax.ppermute``, the NEXT slice in flight while the current one's
-        edge bucket is consumed as a partial ``segment_sum``).  Pipelined
-        requires the ``bucket_by_src`` edge layout, a single-axis mesh with
-        >= 2 shards, and the streamed eMA mode; on such layouts the
-        *blocking* streamed path runs the SAME per-source-shard bucket fold
-        in the SAME ring order (reading each owner's rows out of its one
+      comm_schedule: per-stage comm mode ``(plan_idx, sub_idx) -> mode``
+        (the plan-time ``CostModel.comm_schedule`` decision); stages not in
+        the map are ``"blocking"``.  ``"blocking"`` is one ``all_gather``
+        per column batch (the paper's synchronous scheme); ``"pipelined"``
+        is a double-buffered ring: each column batch circulates as
+        per-shard row slices via ``lax.ppermute``, the NEXT slice in flight
+        while the current one's edge bucket is consumed as a partial
+        ``segment_sum``.  Pipelined requires the ``bucket_by_src`` edge
+        layout and a single-axis mesh with >= 2 shards; on such layouts the
+        *blocking* path runs the SAME per-source-shard bucket fold in the
+        SAME ring order (reading each owner's rows out of its one
         all-gathered buffer), so counts are **bit-exact** across the two
         modes by construction.
-      comm_schedule: optional per-stage override map ``(plan_idx, sub_idx)
-        -> mode`` (the plan-time ``CostModel.comm_schedule`` decision);
-        stages not in the map use ``comm_mode``.
       bucket_stride: the ``ShardedGraph.bucket_stride`` of the
         ``bucket_by_src`` layout (required whenever any stage is pipelined).
     """
@@ -394,65 +360,36 @@ def make_batched_count_fn(
     if len(ks) != 1:
         raise ValueError(f"all plans must share one k, got {sorted(ks)}")
     k = ks.pop()
-    if ema_mode not in ("streamed", "loop", "vectorized"):
-        raise ValueError(f"unknown ema_mode {ema_mode!r}")
-    if comm_mode not in ("blocking", "pipelined"):
-        raise ValueError(f"unknown comm_mode {comm_mode!r}")
 
     axes = tuple(mesh.axis_names)
     n_shards = int(np.prod(mesh.devices.shape))
     rows = n_padded // n_shards
-    pad_unit = column_batch or 128
 
     comm_schedule = dict(comm_schedule or {})
     bad = {m for m in comm_schedule.values() if m not in ("blocking", "pipelined")}
     if bad:
         raise ValueError(f"unknown comm_schedule mode(s) {sorted(bad)}")
-    any_pipelined = comm_mode == "pipelined" or "pipelined" in comm_schedule.values()
-    if any_pipelined:
-        if ema_mode != "streamed":
-            raise ValueError(
-                f"comm_mode='pipelined' requires ema_mode='streamed' "
-                f"(got {ema_mode!r}) — the ring consumes each slice inside "
-                "the fused SpMM+eMA sweep"
-            )
-        if column_batch is None:
-            raise ValueError("comm_mode='pipelined' needs a finite column_batch")
+    if "pipelined" in comm_schedule.values():
         if len(axes) != 1:
             raise ValueError(
-                f"comm_mode='pipelined' rings a single mesh axis (got {axes})"
+                f"a pipelined stage rings a single mesh axis (got {axes})"
             )
         if n_shards < 2:
-            raise ValueError("comm_mode='pipelined' needs >= 2 shards")
+            raise ValueError("a pipelined stage needs >= 2 shards")
         if bucket_stride is None or n_shards * bucket_stride != edges_per_shard:
             raise ValueError(
-                "comm_mode='pipelined' needs the bucket_by_src edge layout: "
+                "a pipelined stage needs the bucket_by_src edge layout: "
                 f"bucket_stride={bucket_stride!r} with edges_per_shard="
                 f"{edges_per_shard} and n_shards={n_shards}"
             )
 
-    track_products = ema_mode != "streamed"
-    if canons is not None:
-        # legacy canons override: the DP walk keys states by THESE strings,
-        # so the liveness schedule must be derived from them too (a plan's
-        # schedule would disagree — don't build one)
-        free_at = schedule_liveness(plans, canons, track_products=track_products)
-    else:
-        if plan_ir is None:
-            # legacy surface (launch/cells probes, direct tests): plan the
-            # set here — the schedule must come from ONE planner either way
-            from repro.plan.ir import build_template_plan
-
-            plan_ir = build_template_plan([p.template for p in plans], plans=plans)
-        canons = plan_ir.canons
-        # the plan's liveness schedule: only the non-streamed eMA modes
-        # memoize aggregate products, so they free against that variant
-        free_at = plan_ir.liveness(track_products=track_products)
+    canons = plan_ir.canons
+    free_at = plan_ir.free_at
     # stages are named by their shared-passive exec group's leader, as the
     # local engine (which runs each group as one sweep) names them
     leader_of = {
         member: leader
-        for leader, members in (plan_ir.exec_groups.items() if plan_ir is not None else ())
+        for leader, members in plan_ir.exec_groups.items()
         for member in members
     }
 
@@ -468,35 +405,8 @@ def make_batched_count_fn(
             stage_table_key[(p_idx, i)] = key
             if key in tables_dev:
                 continue
-            if ema_mode == "streamed":
-                tables_dev[key] = _streamed_stage_tables(t, pad_unit)
-                table_specs[key] = (P(None, None),) * 4
-            else:
-                tables_dev[key] = (jnp.asarray(t.idx_a), jnp.asarray(t.idx_p))
-                table_specs[key] = (P(None, None),) * 2
-
-    def spmm_batched(m_p, src, dst_local, edge_mask):
-        """Column-batched all-gather SpMM; m_p: (rows, B, C_pad) local."""
-        bsz, c_pad = m_p.shape[1], m_p.shape[2]
-        if column_batch is None:
-            full = _compressed_gather(m_p, axes, gather_dtype)
-            return _gather_segment_sum(
-                full, src, dst_local, edge_mask, rows, accum_dtype, axes
-            )
-        n_batches = c_pad // column_batch
-
-        def body(b_idx, acc):
-            cols = jax.lax.dynamic_slice(
-                m_p, (0, 0, b_idx * column_batch), (rows, bsz, column_batch)
-            )
-            full = _compressed_gather(cols, axes, gather_dtype)
-            bcol = _gather_segment_sum(
-                full, src, dst_local, edge_mask, rows, accum_dtype, axes
-            )
-            return jax.lax.dynamic_update_slice(acc, bcol, (0, 0, b_idx * column_batch))
-
-        init = _pvary_missing(jnp.zeros(m_p.shape, accum_dtype), axes)
-        return jax.lax.fori_loop(0, n_batches, body, init)
+            tables_dev[key] = _streamed_stage_tables(t, column_batch)
+            table_specs[key] = (P(None, None),) * 4
 
     # the bucketed consume is shared by the ring AND the single-axis
     # blocking path so the two modes fold bit-identically (see below)
@@ -580,7 +490,7 @@ def make_batched_count_fn(
     ):
         """Fused per-batch SpMM -> eMA: gather a column batch, reduce it, and
         immediately scatter its contributions into M_s (B never exists)."""
-        cb = pad_unit
+        cb = column_batch
         bsz = m_p.shape[1]
         n_batches = m_p.shape[2] // cb
         ent_out, ent_ia, ent_ip, ent_valid = stream_tbl
@@ -631,33 +541,22 @@ def make_batched_count_fn(
             init = _pvary_missing(jnp.zeros((rows, bsz, n_out), accum_dtype), axes)
         return jax.lax.fori_loop(0, n_batches, body, init)
 
-    def ema_loop(m_a, b, idx_a, idx_p):
-        """Vertex-local eMA over fused (rows, B, C) state (Algorithm 5)."""
-        init = _pvary_missing(
-            jnp.zeros((rows, m_a.shape[1], idx_a.shape[0]), accum_dtype), axes
-        )
-        return _ema_apply_fused(m_a, b, idx_a, idx_p, init)
-
     def local_count(colors, src, dst_local, edge_mask, tables):
         # colors: (B, rows) local slice of the (B, n_padded) coloring batch.
         def pad_c(m):
             c = m.shape[-1]
-            return jnp.pad(m, ((0, 0), (0, 0), (0, _pad_cols(c, pad_unit) - c)))
+            return jnp.pad(m, ((0, 0), (0, 0), (0, _pad_cols(c, column_batch) - c)))
 
-        def free(pos, slots, prods):
+        def free(pos, slots):
             # Algorithm 5's in-place storage, liveness-scheduled: drop DP
-            # states / memoized SpMM products after their last reader.
+            # states after their last reader.
             for key in free_at.get(pos, ()):
-                if isinstance(key, tuple):
-                    prods.pop(key[1], None)
-                else:
-                    slots.pop(key, None)
+                slots.pop(key, None)
 
         with tracing.scope("colors"):
             leaf = pad_c(jax.nn.one_hot(colors.T, k, dtype=store_dtype))  # (rows, B, k_pad)
         executed = set()
         slots = {}
-        prods = {}
         totals = []
         pos = 0
         for p_idx, plan in enumerate(plans):
@@ -674,35 +573,14 @@ def make_batched_count_fn(
                     tkey = stage_table_key[(p_idx, i)]
                     lp, li = leader_of.get((p_idx, i), (p_idx, i))
                     with tracing.scope(tracing.stage(lp, li, plans[lp].partition.subs[li].size)):
-                        if ema_mode == "streamed":
-                            m_s = spmm_ema_streamed(
-                                m_p, m_a, src, dst_local, edge_mask,
-                                plan.tables[i].n_out, tables[tkey],
-                                mode=comm_schedule.get((p_idx, i), comm_mode),
-                            )
-                        else:
-                            p_key = pc[sub.passive]
-                            if p_key not in prods:
-                                with tracing.scope("spmm"):
-                                    prods[p_key] = spmm_batched(m_p, src, dst_local, edge_mask)
-                            b = prods[p_key]
-                            idx_a, idx_p = tables[tkey]
-                            with tracing.scope("ema"):
-                                if ema_mode == "vectorized":
-                                    # probe mode: single gather-FMA einsum (no
-                                    # fori_loop) so the split-axis work is
-                                    # visible to cost_analysis
-                                    m_s = jnp.einsum(
-                                        "rbos,rbos->rbo",
-                                        jnp.take(m_a, idx_a, axis=2).astype(accum_dtype),
-                                        jnp.take(b, idx_p, axis=2),
-                                        precision=jax.lax.Precision.HIGHEST,
-                                    )
-                                else:
-                                    m_s = ema_loop(m_a, b, idx_a, idx_p)
+                        m_s = spmm_ema_streamed(
+                            m_p, m_a, src, dst_local, edge_mask,
+                            plan.tables[i].n_out, tables[tkey],
+                            mode=comm_schedule.get((p_idx, i), "blocking"),
+                        )
                         with tracing.scope("ema"):
                             slots[ckey] = pad_c(m_s.astype(store_dtype))
-                free(pos, slots, prods)
+                free(pos, slots)
                 pos += 1
             with tracing.scope("root"):
                 root = slots[pc[plan.partition.root_index]].astype(accum_dtype)
@@ -711,7 +589,7 @@ def make_batched_count_fn(
                 # reduction
                 total_local = root.sum(axis=2).sum(axis=0)
                 totals.append(jax.lax.psum(total_local, axes))  # (B,), replicated
-            free(pos, slots, prods)
+            free(pos, slots)
             pos += 1
         with tracing.scope("root"):
             return jnp.stack(totals, axis=1).astype(jnp.float32)  # (B, T)
@@ -728,55 +606,3 @@ def make_batched_count_fn(
         return mapped(colors_batch, src, dst_local, edge_mask, tables_dev)
 
     return count
-
-
-def make_distributed_count_fn(
-    plan: CountingPlan,
-    mesh: Mesh,
-    n_padded: int,
-    edges_per_shard: int,
-    column_batch: Optional[int] = 128,
-    ema_mode: str = "loop",
-    gather_dtype=None,
-):
-    """One-coloring, one-template distributed count (compat / probe surface).
-
-    A thin ``B=1`` wrapper over :func:`make_batched_count_fn` — kept for the
-    dry-run/probe tooling (``launch.cells``) and ad-hoc single-coloring
-    checks.  Estimation runs should use the engine's ``mesh`` backend
-    (``CountingEngine(..., backend="mesh", mesh=mesh)``), which batches
-    chunks of colorings into each collective.
-
-    Signature of the returned fn::
-
-      (colors (n_padded,) i32, src (S*E,) i32, dst_local (S*E,) i32,
-       edge_mask (S*E,) f32) -> scalar f32 raw colorful total
-
-    Split tables are built once here and closure-captured (they are no
-    longer an argument).
-    """
-    batched = make_batched_count_fn(
-        [plan],
-        mesh,
-        n_padded,
-        edges_per_shard,
-        column_batch=column_batch,
-        ema_mode=ema_mode,
-        gather_dtype=gather_dtype,
-    )
-
-    def count(colors, src, dst_local, edge_mask):
-        return batched(colors[None, :], src, dst_local, edge_mask)[0, 0]
-
-    return count
-
-
-def distributed_input_specs(n_padded: int, n_shards: int, edges_per_shard: int):
-    """ShapeDtypeStructs for the one-coloring distributed count (dry-run)."""
-    e_total = n_shards * edges_per_shard
-    return (
-        jax.ShapeDtypeStruct((n_padded,), jnp.int32),   # colors
-        jax.ShapeDtypeStruct((e_total,), jnp.int32),    # src (global)
-        jax.ShapeDtypeStruct((e_total,), jnp.int32),    # dst (local)
-        jax.ShapeDtypeStruct((e_total,), jnp.float32),  # edge mask
-    )

@@ -256,7 +256,7 @@ class CountingEngine:
         ``None`` auto-sizes: ``min(16, max passive columns)`` on the local
         backends, ``min(128, max passive columns)`` on the mesh backend
         (where a batch is also one all-gather collective).
-      mesh / ema_mode / gather_dtype / balance_degrees / mesh_comm:
+      mesh / gather_dtype / balance_degrees / mesh_comm:
         mesh-backend knobs — see :class:`repro.exec.mesh.MeshBackend`
         (``mesh_comm`` forces ``blocking`` | ``pipelined`` collectives;
         ``None`` lets ``REPRO_MESH_COMM`` or the cost model's
@@ -287,7 +287,6 @@ class CountingEngine:
         interpret: bool = False,
         mesh=None,
         column_batch: Optional[int] = None,
-        ema_mode: str = "streamed",
         gather_dtype=None,
         balance_degrees: bool = True,
         mesh_comm: Optional[str] = None,
@@ -406,7 +405,6 @@ class CountingEngine:
             block_size=block_size,
             mesh=mesh,
             column_batch=column_batch,
-            ema_mode=ema_mode,
             gather_dtype=gather_dtype,
             balance_degrees=balance_degrees,
             mesh_comm=mesh_comm,

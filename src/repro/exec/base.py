@@ -88,7 +88,6 @@ def make_backend(engine, **kwargs) -> "EngineBackend":
             engine,
             kwargs.get("mesh"),
             column_batch=kwargs.get("column_batch"),
-            ema_mode=kwargs.get("ema_mode", "streamed"),
             gather_dtype=kwargs.get("gather_dtype"),
             balance_degrees=kwargs.get("balance_degrees", True),
             comm=kwargs.get("mesh_comm"),

@@ -86,9 +86,6 @@ class MeshBackend(EngineBackend):
       mesh: the ``jax.sharding.Mesh`` to run on (required).
       column_batch: passive columns per collective; ``None`` auto-sizes via
         the cost model (``min(128, max passive columns)``).
-      ema_mode: ``"streamed"`` (default — fused per-batch SpMM->eMA, the B
-        matrix never materializes) or ``"loop"`` (paper-faithful Algorithm
-        5 with the SpMM product memoized per canonical passive form).
       gather_dtype: optional wire dtype for compressed collectives
         (e.g. ``jnp.bfloat16``); accumulation stays fp32.
       balance_degrees: relabel vertices round-robin by degree rank before
@@ -101,9 +98,8 @@ class MeshBackend(EngineBackend):
       comm: ``"blocking"`` | ``"pipelined"`` | ``None`` (auto).  Explicit
         beats the ``REPRO_MESH_COMM`` env override beats the cost model's
         per-stage ``comm_schedule`` decision.  A forced ``pipelined`` that
-        the geometry cannot support (single shard, multi-axis mesh,
-        non-streamed eMA) falls back to blocking with the reason recorded
-        in ``describe_comm()``.
+        the geometry cannot support (single shard, multi-axis mesh) falls
+        back to blocking with the reason recorded in ``describe_comm()``.
     """
 
     name = "mesh"
@@ -119,7 +115,6 @@ class MeshBackend(EngineBackend):
         mesh,
         *,
         column_batch: Optional[int] = None,
-        ema_mode: str = "streamed",
         gather_dtype=None,
         balance_degrees: bool = True,
         comm: Optional[str] = None,
@@ -135,7 +130,6 @@ class MeshBackend(EngineBackend):
 
         mesh = auto_axes(mesh)
         self.mesh = mesh
-        self.ema_mode = ema_mode
         self.gather_dtype = gather_dtype
         n_shards = int(np.prod(mesh.devices.shape))
         with tracing.span("shard_graph"):
@@ -216,12 +210,10 @@ class MeshBackend(EngineBackend):
             self.sharded.n_padded,
             self.sharded.edges_per_shard,
             column_batch=self.column_batch,
-            ema_mode=ema_mode,
             gather_dtype=gather_dtype,
             plan_ir=engine.plan_ir,
             store_dtype=engine.policy.store_dtype,
             accum_dtype=engine.policy.accum_dtype,
-            comm_mode="blocking",
             comm_schedule=stage_modes,
             bucket_stride=self.sharded.bucket_stride,
         )
@@ -233,14 +225,14 @@ class MeshBackend(EngineBackend):
         dtype.  Blocking all-gathers each column batch once and the ring
         passes it in ``n_shards - 1`` hops, so both move the same bytes."""
         cb = self.column_batch
-        moved = {}  # the eMA modes other than streamed aggregate each passive state once
-        for s in self.engine.plan_ir.stages:
-            if not s.is_leaf:
-                key = s.position if self.ema_mode == "streamed" else s.passive_canon
-                moved[key] = -(-s.passive_columns // cb) * cb
+        moved = sum(
+            -(-s.passive_columns // cb) * cb
+            for s in self.engine.plan_ir.stages
+            if not s.is_leaf
+        )
         wire = jnp.dtype(self.gather_dtype or self.engine.policy.store_dtype).itemsize
         sg = self.sharded
-        return (sg.n_shards - 1) * sg.rows_per_shard * chunk * sum(moved.values()) * wire
+        return (sg.n_shards - 1) * sg.rows_per_shard * chunk * moved * wire
 
     def publish_gauges(self, chunk: int) -> None:
         """Set the mesh gauges of :mod:`repro.tracing` for a launch of
@@ -253,11 +245,6 @@ class MeshBackend(EngineBackend):
 
     def _pipeline_eligibility(self, n_shards: int):
         """Whether this geometry can run the ring at all — ``(ok, why)``."""
-        if self.ema_mode != "streamed":
-            return False, (
-                f"ema_mode={self.ema_mode!r} — the ring consumes slices "
-                "inside the fused streamed sweep only"
-            )
         if len(self.mesh.axis_names) != 1:
             return False, (
                 f"mesh axes {tuple(self.mesh.axis_names)} — the ring "
@@ -321,5 +308,5 @@ class MeshBackend(EngineBackend):
         """Per-shard live DP state: local rows times the liveness-aware
         peak of padded M columns under the shared multi-template schedule."""
         return self.engine.cost.mesh_resident_elements(
-            self.sharded.rows_per_shard, self.column_batch, self.ema_mode
+            self.sharded.rows_per_shard, self.column_batch
         )

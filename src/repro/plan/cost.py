@@ -758,17 +758,12 @@ class CostModel:
         edges = min(edges_per_shard, EDGE_CHUNK)
         return (n_padded + edges) * self._lane_padded(column_batch)
 
-    def mesh_resident_elements(
-        self, rows_per_shard: int, column_batch: int, ema_mode: str = "streamed"
-    ) -> int:
+    def mesh_resident_elements(self, rows_per_shard: int, column_batch: int) -> int:
         """Per-shard live DP state: local rows times the liveness-aware
-        peak of padded M columns (memoized SpMM products count too in the
-        non-streamed eMA modes)."""
-        peak = self.plan.padded_peak_columns(
-            pad_unit=self._lane_padded(column_batch),
-            track_products=(ema_mode != "streamed"),
+        peak of padded M columns."""
+        return rows_per_shard * self.plan.padded_peak_columns(
+            pad_unit=self._lane_padded(column_batch)
         )
-        return rows_per_shard * peak
 
     def comm_schedule(
         self,

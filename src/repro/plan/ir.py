@@ -23,7 +23,7 @@ the canonical states that are dead after position ``pos``.
 
 from __future__ import annotations
 
-from dataclasses import dataclass, field
+from dataclasses import dataclass
 from typing import Callable, Dict, List, Mapping, Optional, Sequence, Tuple, Union
 
 from repro.core.colorsets import binom
@@ -136,9 +136,6 @@ class TemplatePlan:
       occurrence, in execution order, with width annotations.
     * ``free_at`` — liveness: position -> canonical states dead after it
       (the fused pipeline's schedule — no aggregate products exist).
-    * ``free_at_products`` — the same schedule when memoized SpMM products
-      are also tracked (the mesh backend's loop/vectorized eMA modes);
-      product keys are ``("prod", canon)`` tuples.
     * ``exec_groups`` — shared-passive execution groups: leader
       ``(plan_idx, sub_idx)`` -> members (leader first).  All members read
       the same passive canonical form and their actives are live before
@@ -159,7 +156,6 @@ class TemplatePlan:
     canons: Tuple[Tuple[str, ...], ...]
     stages: Tuple[PlanStage, ...]
     free_at: Mapping[int, Tuple[str, ...]]
-    free_at_products: Mapping[int, Tuple] = field(repr=False)
     exec_groups: Mapping[Tuple[int, int], Tuple[Tuple[int, int], ...]]
     peak_columns: int
     max_passive_columns: int
@@ -210,18 +206,11 @@ class TemplatePlan:
                 return s
         return None
 
-    def liveness(self, track_products: bool = False) -> Mapping[int, Tuple]:
-        """The liveness schedule an executor should free against."""
-        return self.free_at_products if track_products else self.free_at
-
-    def padded_peak_columns(self, pad_unit: int, track_products: bool = False) -> int:
+    def padded_peak_columns(self, pad_unit: int) -> int:
         """Liveness peak with every state's columns padded up to
         ``pad_unit`` (the mesh backend pads to its all-gather batch)."""
         return liveness_peak_columns(
-            self.counting_plans,
-            self.canons,
-            pad_unit=pad_unit,
-            track_products=track_products,
+            self.counting_plans, self.canons, pad_unit=pad_unit
         )
 
     def peak_footprint(self, footprint: Callable[[int], int]) -> int:
@@ -479,12 +468,6 @@ def build_template_plan(
         p: tuple(keys)
         for p, keys in schedule_liveness(counting_plans, canons).items()
     }
-    free_at_products = {
-        p: tuple(keys)
-        for p, keys in schedule_liveness(
-            counting_plans, canons, track_products=True
-        ).items()
-    }
 
     return TemplatePlan(
         k=k,
@@ -493,7 +476,6 @@ def build_template_plan(
         canons=canons,
         stages=tuple(stages),
         free_at=free_at,
-        free_at_products=free_at_products,
         exec_groups=_build_shared_passive_groups(counting_plans, canons),
         peak_columns=liveness_peak_columns(counting_plans, canons),
         max_passive_columns=max_passive,

@@ -5,9 +5,8 @@
 ``repro.serve.frontend`` is the async production front door above it
 (futures, per-tenant QoS tiers and rate limits, cost-model backpressure,
 streaming progress, background engine warming) with its QoS primitives in
-``repro.serve.qos``.  ``repro.serve.engine`` is the unrelated LM
-continuous-batching demo and is NOT imported here (it pulls in the
-transformer stack — import it explicitly if you want it).
+``repro.serve.qos``, its retry and quarantine policy in
+``repro.serve.resilience`` and the engine cache in ``repro.serve.cache``.
 """
 
 from .cache import EngineCache

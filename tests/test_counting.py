@@ -33,7 +33,7 @@ from repro.core import (
     star_template,
 )
 
-TEMPLATES_SMALL = ["u3", "path4", "star4", "u5-1", "u5-2", "u6"]
+TEMPLATES_SMALL = ["u3", "path4", "star4", "u5-1", "u5-2", "u6", "u7"]
 
 
 def _spmm(graph):

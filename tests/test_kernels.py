@@ -154,7 +154,7 @@ def test_ema_apply_matches_oracle(k, m, m_a, n):
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("tname", ["u3", "u5-2", "u6"])
+@pytest.mark.parametrize("tname", ["u3", "u5-1", "u5-2", "u6", "u7"])
 def test_full_dp_on_pallas_kernels(tname):
     g = rmat_graph(96, 380, seed=4)
     t = get_template(tname)

@@ -41,18 +41,17 @@ CHUNK = 3
 
 
 def moved_columns(engine):
-    # the program's stage walk: each rooted canonical form runs once; the
-    # loop eMA aggregates each passive state once and keeps the product
+    # the program's stage walk: each rooted canonical form runs once and
+    # aggregates its passive state
     cb = engine.backend_impl.column_batch
-    done, moved, cols = set(), set(), 0
+    done, cols = set(), 0
     for plan, canons in zip(engine.plans, engine.plan_ir.canons):
         for i, sub in enumerate(plan.partition.subs):
             if canons[i] in done:
                 continue
             done.add(canons[i])
-            if sub.is_leaf or (engine.backend_impl.ema_mode == "loop" and canons[sub.passive] in moved):
+            if sub.is_leaf:
                 continue
-            moved.add(canons[sub.passive])
             cols += -(-comb(plan.k, plan.tables[i].m_p) // cb) * cb
     return cols
 
@@ -62,12 +61,12 @@ def build(**kw):
 
 
 out = {}
-for comm, ema in (("blocking", "streamed"), ("pipelined", "streamed"), ("blocking", "loop")):
+for comm in ("blocking", "pipelined"):
     for policy, wire in (("fp32", 4), ("bf16", 2)):
-        engine = build(mesh_comm=comm, ema_mode=ema, dtype_policy=policy)
+        engine = build(mesh_comm=comm, dtype_policy=policy)
         sg = engine.backend_impl.sharded
         got = tracing.gauges()
-        out[f"{comm}/{ema}/{policy}"] = {
+        out[f"{comm}/{policy}"] = {
             "bytes": got["mesh_collective_bytes"],
             "want": (sg.n_shards - 1) * sg.rows_per_shard * CHUNK * moved_columns(engine) * wire,
             "chunk": got["mesh_chunk"],
@@ -109,17 +108,15 @@ def gauges(request):
     return _child(request.param)
 
 
-RUNS = [f"{comm}/{ema}/{policy}" for comm, ema in (("blocking", "streamed"), ("pipelined", "streamed"),
-                                                   ("blocking", "loop")) for policy in ("fp32", "bf16")]
+RUNS = [f"{comm}/{policy}" for comm in ("blocking", "pipelined") for policy in ("fp32", "bf16")]
 
 
 def test_collective_bytes_follow_the_stage_tables(gauges):
     for key in RUNS:
         assert gauges[key]["bytes"] == gauges[key]["want"] > 0, key
         assert gauges[key]["chunk"] == 3
-    assert gauges["blocking/streamed/fp32"]["bytes"] == gauges["pipelined/streamed/fp32"]["bytes"]
-    assert gauges["blocking/streamed/bf16"]["bytes"] * 2 == gauges["blocking/streamed/fp32"]["bytes"]
-    assert gauges["blocking/loop/bf16"]["bytes"] * 2 == gauges["blocking/loop/fp32"]["bytes"]
+    assert gauges["blocking/fp32"]["bytes"] == gauges["pipelined/fp32"]["bytes"]
+    assert gauges["blocking/bf16"]["bytes"] * 2 == gauges["blocking/fp32"]["bytes"]
 
 
 def test_edge_gauges_are_shard_graph_geometry(gauges):
